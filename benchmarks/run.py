@@ -23,10 +23,7 @@ def main() -> None:
         (bench_roofline, "roofline table from dry-run"),
     ):
         print(f"# --- {tag} ---", file=sys.stderr)
-        try:
-            mod.main()
-        except Exception as e:  # keep the harness robust
-            print(f"{mod.__name__},nan,ERROR:{e}")
+        mod.main()  # a failing phase fails the run
     print(f"# total {time.time()-t0:.1f}s", file=sys.stderr)
 
 

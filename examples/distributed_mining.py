@@ -53,13 +53,11 @@ def main():
     host = {s: len(g_) for s, (g_, _) in
             aggregate_host(np.asarray(sigs), gid).items()}
 
-    from repro.compat import set_mesh_compat
-
     mesh = jax.make_mesh((4, 2), ("data", "model"))
     step = make_mining_step(mesh, k=1024, db_axes=("data",),
                             tok_axis="model")
     gid_local = (gid % (len(db) // 4)).astype(np.int32)
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         uniq, counts, _ = step(
             jnp.asarray(tdb.tokens), jnp.asarray(gid_local),
             jnp.asarray(phi), jnp.asarray(psi), jnp.asarray(valid),

@@ -1,11 +1,12 @@
 """Fused trie-walk megakernel: the whole subtree walk in one dispatch.
 
-* ``ref.py``       - ``trie_walk_core``: the slot-topological walk over
-                     in-kernel frontier buffers (jnp; also the kernel
-                     body), bit-identical to the per-level scan in
+* ``ref.py``       - ``trie_walk_core``: the jnp slot-topological walk
+                     over in-kernel frontier buffers (the kernel's
+                     oracle), bit-identical to the per-level scan in
                      repro.serving.batch.
 * ``trie_walk.py`` - ``trie_walk_blocked``: the Pallas kernel gridded
-                     over (sequence, depth-1 subtree) cells, behind the
+                     over (sequence, depth-1 subtree) cells, its body
+                     the same walk in a form Mosaic lowers, behind the
                      same interpret/lane-pad backend auto-select as the
                      containment kernel.
 

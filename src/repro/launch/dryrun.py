@@ -17,7 +17,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 from ..configs.registry import get_arch, list_archs  # noqa: E402
 from ..models import common  # noqa: E402
 from ..roofline import analysis  # noqa: E402
-from .mesh import make_production_mesh, set_mesh_compat  # noqa: E402
+from .mesh import make_production_mesh  # noqa: E402
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                            "results", "dryrun")
@@ -74,13 +74,13 @@ def lower_cell(arch_id: str, shape: str, multi_pod: bool):
                 b["existing"], jax.ShapeDtypeStruct((), jnp.int32),
                 jax.ShapeDtypeStruct((), jnp.int32),
                 jax.ShapeDtypeStruct((), jnp.int32))
-        with set_mesh_compat(mesh):
+        with jax.set_mesh(mesh):
             lowered = step.lower(*args)
     else:
         step, args = arch.make_step(shape, mesh)
         specs = arch.arg_specs(shape, mesh, args)
         shardings = _sharding_tree(specs, mesh)
-        with set_mesh_compat(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(step, in_shardings=shardings).lower(*args)
     t_lower = time.time() - t0
 
@@ -90,8 +90,10 @@ def lower_cell(arch_id: str, shape: str, multi_pod: bool):
 
     hlo = compiled.as_text()
     n_chips = int(np.prod(list(mesh.shape.values())))
+    # the virtual CPU devices stand in for a v5e pod's chips
     roof = analysis.from_compiled(
-        compiled, n_chips, arch.model_flops(shape), hlo_text=hlo
+        compiled, n_chips, arch.model_flops(shape),
+        device_kind="TPU v5 lite", hlo_text=hlo,
     )
     coll = analysis.parse_collectives(hlo)
     return {

@@ -8,8 +8,6 @@ from __future__ import annotations
 
 import jax
 
-from ..compat import set_mesh_compat, shard_map_compat  # noqa: F401
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)

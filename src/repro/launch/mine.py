@@ -7,6 +7,7 @@ import time
 
 from ..data.synthetic import Table3Params, generate_table3_db
 from ..mining.driver import AcceleratedMiner
+from .compile_cache import enable_compile_cache
 
 
 def main():
@@ -27,6 +28,7 @@ def main():
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     params = Table3Params(db_size=args.db_size, v_avg=args.v_avg,
                           n_interstates=args.interstates)

@@ -28,12 +28,15 @@ from __future__ import annotations
 import argparse
 import time
 
+import jax
+
 from ..core.graphseq import pattern_str
 from ..data.synthetic import Table3Params, generate_table3_db
 from ..mining.driver import AcceleratedMiner
 from ..serving.bank import compile_bank
 from ..serving.server import PatternServer
 from ..serving.streaming import StreamingBank
+from .compile_cache import enable_compile_cache
 
 
 def main():
@@ -75,6 +78,7 @@ def main():
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     params = Table3Params(db_size=args.db_size, v_avg=args.v_avg,
                           n_interstates=args.interstates)
@@ -131,6 +135,13 @@ def main():
           f"cache_hits={srv.stats['cache_hits']}")
 
 
+def _host_devices():
+    """One device per host, round-robin, when the process sees several
+    (a four-chip host); None keeps every host on the default device."""
+    devices = jax.devices()
+    return devices if len(devices) > 1 else None
+
+
 def _cluster_main(args, db, sigma):
     """Multi-host serving demo: shard the mined bank across simulated
     hosts, spread the query stream round-robin over arrival hosts, and
@@ -145,7 +156,7 @@ def _cluster_main(args, db, sigma):
     cl = ServingCluster(
         bank, args.hosts, bank_layout=args.bank_layout,
         topk=args.topk, emax=args.emax, max_batch=args.max_batch,
-        use_kernel=args.use_kernel,
+        use_kernel=args.use_kernel, devices=_host_devices(),
     )
     sizes = [len(h.rows) for h in cl.hosts]
     print(f"[serve] bank: {bank.n_patterns} rFTSs sharded "
@@ -189,6 +200,7 @@ def _sharded_stream_main(args, db, sigma):
         db, minsup=sigma, n_hosts=args.hosts, window=window,
         max_len=args.max_len, bank_layout=args.bank_layout,
         emax=args.emax, use_kernel=args.use_kernel,
+        devices=_host_devices(),
     )
     print(f"[serve] seeded in {time.time()-t0:.2f}s: "
           f"{sb.bank.n_patterns} rFTSs")

@@ -14,35 +14,19 @@ import os
 import tempfile
 from typing import Dict, List, Tuple
 
-import zlib
-
 import msgpack
-
-try:
-    import zstandard
-except ImportError:  # optional dep: fall back to stdlib zlib
-    zstandard = None
+import zstandard
 
 from ..core.enumerate_host import Emb
 from ..core.graphseq import Pattern, TR, TRType
 
-_ZSTD_MAGIC = b"\x28\xb5\x2f\xfd"
-
 
 def _compress(raw: bytes) -> bytes:
-    if zstandard is not None:
-        return zstandard.ZstdCompressor(level=3).compress(raw)
-    return zlib.compress(raw, 6)
+    return zstandard.ZstdCompressor(level=3).compress(raw)
 
 
 def _decompress(data: bytes) -> bytes:
-    if data[:4] == _ZSTD_MAGIC:
-        if zstandard is None:
-            raise RuntimeError(
-                "checkpoint is zstd-compressed but zstandard is not installed"
-            )
-        return zstandard.ZstdDecompressor().decompress(data)
-    return zlib.decompress(data)
+    return zstandard.ZstdDecompressor().decompress(data)
 
 
 def _pattern_to_wire(p: Pattern):

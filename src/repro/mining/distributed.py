@@ -37,7 +37,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map_compat
 from .encoding import INVALID_SIG
 from .engine import match_signatures_ref
 
@@ -171,7 +170,8 @@ def make_mining_step(
         P(),                        # existing
         P(), P(), P(),              # nv, n_pat, mode
     )
-    step = shard_map_compat(
-        local_step, mesh, specs_in, (P(), P(), P())
+    step = jax.shard_map(
+        local_step, mesh=mesh, in_specs=specs_in,
+        out_specs=(P(), P(), P()), check_vma=False,
     )
     return jax.jit(step)

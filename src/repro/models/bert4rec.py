@@ -277,10 +277,9 @@ def make_sharded_serve(cfg: Bert4RecConfig, mesh, dp_axes):
         },
         P(dp_dim, None),
     )
-    from ..compat import shard_map_compat
-
-    fn = shard_map_compat(
-        local, mesh, in_specs, (P(dp_dim, None), P(dp_dim, None))
+    fn = jax.shard_map(
+        local, mesh=mesh, in_specs=in_specs,
+        out_specs=(P(dp_dim, None), P(dp_dim, None)), check_vma=False,
     )
 
     def serve(params, batch):

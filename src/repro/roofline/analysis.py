@@ -15,10 +15,31 @@ import dataclasses
 import re
 from typing import Dict, Optional
 
-# TPU v5e per chip
-PEAK_FLOPS = 197e12     # bf16
-HBM_BW = 819e9          # bytes/s
-ICI_BW = 50e9           # bytes/s per link
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    flops: float    # bf16 FLOP/s
+    hbm_bw: float   # bytes/s
+    ici_bw: float   # bytes/s per link
+
+
+# Per-chip peaks keyed by ``jax.Device.device_kind``.  TPU v5e: Google
+# Cloud documentation, "TPU v5e" - 197 TFLOP/s bf16, 819 GB/s HBM,
+# 1,600 Gbit/s interchip interconnect (4 links of 50 GB/s).
+PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """The peaks of ``device_kind``; a chip not in ``PEAKS`` is an
+    error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -68,19 +89,23 @@ class Roofline:
     hbm_bytes_per_chip: float
     collective_bytes_per_chip: float
     n_chips: int
+    device_kind: str
     model_flops: float = 0.0
+
+    def __post_init__(self):
+        self.peaks = chip_peaks(self.device_kind)
 
     @property
     def t_compute(self) -> float:
-        return self.flops_per_chip / PEAK_FLOPS
+        return self.flops_per_chip / self.peaks.flops
 
     @property
     def t_memory(self) -> float:
-        return self.hbm_bytes_per_chip / HBM_BW
+        return self.hbm_bytes_per_chip / self.peaks.hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.collective_bytes_per_chip / ICI_BW
+        return self.collective_bytes_per_chip / self.peaks.ici_bw
 
     @property
     def bottleneck(self) -> str:
@@ -104,7 +129,7 @@ class Roofline:
         t = max(self.t_compute, self.t_memory, self.t_collective)
         if t <= 0:
             return 0.0
-        return self.model_flops / (self.n_chips * PEAK_FLOPS * t)
+        return self.model_flops / (self.n_chips * self.peaks.flops * t)
 
     def to_dict(self) -> dict:
         extra = {}
@@ -118,6 +143,7 @@ class Roofline:
             "hbm_bytes_per_chip": self.hbm_bytes_per_chip,
             "collective_bytes_per_chip": self.collective_bytes_per_chip,
             "n_chips": self.n_chips,
+            "device_kind": self.device_kind,
             "model_flops": self.model_flops,
             "t_compute": self.t_compute,
             "t_memory": self.t_memory,
@@ -129,6 +155,7 @@ class Roofline:
 
 
 def from_compiled(compiled, n_chips: int, model_flops: float,
+                  device_kind: str,
                   hlo_text: Optional[str] = None) -> Roofline:
     from . import hlo_cost
 
@@ -151,6 +178,7 @@ def from_compiled(compiled, n_chips: int, model_flops: float,
         hbm_bytes_per_chip=byts,
         collective_bytes_per_chip=coll_bytes,
         n_chips=n_chips,
+        device_kind=device_kind,
         model_flops=model_flops,
     )
     r.raw_cost_analysis = {"flops": raw_flops,  # type: ignore[attr-defined]

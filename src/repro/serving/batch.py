@@ -322,11 +322,11 @@ def _join(tokens, order, start, count, cell_b, cell_steps, *,
     valid = jnp.ones((N, 1), jnp.bool_)
     overflow = jnp.zeros((N,), jnp.bool_)
 
-    # NOTE: an unrolled python loop, not lax.scan - the scan + shard_map
-    # combination miscompiles on the jax 0.4 CPU backend (dropped
-    # matches on non-zero data shards, see the gated repro in
-    # tests/test_scan_shardmap.py), and L is small enough that
-    # unrolling is also the faster choice.
+    # NOTE: an unrolled python loop, not lax.scan.  It was chosen when
+    # scan inside shard_map dropped matches on the jax 0.4 CPU backend;
+    # tests/test_scan_shardmap.py shows the installed jax agrees, so
+    # the loop stays unrolled only until a measurement on the chip
+    # picks between the two.
     for k in range(L):
         step_k = cell_steps[:, k]
         if uniform_length and k == L - 1:

@@ -68,6 +68,7 @@ results and the frequent map must be bit-equal to the single-host
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from collections import OrderedDict
@@ -93,6 +94,14 @@ from .streaming import StreamingBank
 from .trie import TrieBank, build_trie, extend_trie
 
 
+def _on_device(device):
+    """Context in which new arrays and computations land on ``device``
+    (no-op for ``None``: the default device)."""
+    if device is None:
+        return contextlib.nullcontext()
+    return jax.default_device(device)
+
+
 @dataclasses.dataclass
 class ClusterHost:
     """One simulated host: its bank shard server, owned global rows,
@@ -115,11 +124,9 @@ class ClusterHost:
     def call(self, fn, *args, **kw):
         if self.injector is not None:
             self.injector.on_call(self.hid)
-        with trace.span("cluster.host_call", host=self.hid):
-            if self.device is None:
-                return fn(*args, **kw)
-            with jax.default_device(self.device):
-                return fn(*args, **kw)
+        with trace.span("cluster.host_call", host=self.hid), \
+                _on_device(self.device):
+            return fn(*args, **kw)
 
 
 def _make_hosts(
@@ -135,20 +142,20 @@ def _make_hosts(
 ) -> List[ClusterHost]:
     hosts = []
     for hid, rows in enumerate(placement.rows):
-        shard = slice_bank(bank, rows)
-        # per-host namespaces on the shared registry: shard counters
+        device = None if devices is None else devices[hid % len(devices)]
+        # built on the host's device, so its tables live there.
+        # Per-host namespaces on the shared registry: shard counters
         # stay separate (ServingCluster.stats sums them), yet survive
         # re-planning because the registry outlives the servers
-        srv = PatternServer(shard, bank_layout=bank_layout,
-                            metrics=metrics,
-                            metrics_ns=f"serving.server.h{hid}",
-                            **(server_kw or {}))
+        with _on_device(device):
+            srv = PatternServer(slice_bank(bank, rows),
+                                bank_layout=bank_layout, metrics=metrics,
+                                metrics_ns=f"serving.server.h{hid}",
+                                **(server_kw or {}))
         hosts.append(ClusterHost(
             hid=hid, rows=rows, server=srv,
             l1=OrderedDict(), l2=OrderedDict(),
-            l1_size=l1_size, l2_size=l2_size,
-            device=None if devices is None else
-            devices[hid % len(devices)],
+            l1_size=l1_size, l2_size=l2_size, device=device,
         ))
     return hosts
 

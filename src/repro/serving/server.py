@@ -433,6 +433,15 @@ class PatternServer:
         # re-gather them from the masked node table
         self._pk_req = jnp.asarray(self._tpack.pack_req(self._node_req_np))
 
+    def device_tables(self) -> List[jax.Array]:
+        """The bank tables this server keeps on device - what a
+        placement check inspects."""
+        out = [self._req] + [steps for _, steps in self._groups]
+        for name in ("_node_req", "_pk_steps", "_pk_parent", "_pk_req"):
+            if hasattr(self, name):
+                out.append(getattr(self, name))
+        return out
+
     # ------------------------------------------------------------- masking
     def set_row_mask(self, active: Optional[np.ndarray]) -> None:
         """Install (or with ``None`` clear) a tombstone mask: rows where

@@ -31,7 +31,6 @@ import numpy as np
 import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..compat import shard_map_compat
 from .batch import batch_contains_ref, trie_contains_ref
 from .trie import TrieBank
 
@@ -68,7 +67,8 @@ def make_serving_step(
         P(pat_axis),              # pattern_valid
     )
     specs_out = (P(db_axis, pat_axis), P(db_axis, pat_axis))
-    step = shard_map_compat(local_step, mesh, specs_in, specs_out)
+    step = jax.shard_map(local_step, mesh=mesh, in_specs=specs_in,
+                         out_specs=specs_out, check_vma=False)
     return jax.jit(step)
 
 
@@ -150,5 +150,6 @@ def make_trie_serving_step(
         P(pat_axis),               # pattern_valid
     )
     specs_out = (P(db_axis, pat_axis), P(db_axis, pat_axis))
-    step = shard_map_compat(local_step, mesh, specs_in, specs_out)
+    step = jax.shard_map(local_step, mesh=mesh, in_specs=specs_in,
+                         out_specs=specs_out, check_vma=False)
     return jax.jit(step)

@@ -600,6 +600,12 @@ for layout in ("flat", "trie"):
     assert len({h.device for h in cl.hosts}) == 8, "one device per host"
     got = cl.exact_rows(queries)
     assert np.array_equal(got, want), layout
+    # each host's tables live on its own device, also after a re-upload
+    for mask in (None, np.arange(bank.n_patterns) % 2 == 0):
+        cl.set_row_mask(mask)
+        for h in cl.hosts:
+            for arr in h.server.device_tables():
+                assert arr.devices() == {h.device}, (layout, h.hid)
 print("CLUSTER-OK", bank.n_patterns)
 """
 

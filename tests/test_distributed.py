@@ -34,13 +34,12 @@ sigs = match_signatures(
     jnp.int32(0), jnp.int32(0), jnp.int32(MODE_ROOT))
 host = {s: len(gs) for s, (gs, _) in aggregate_host(np.asarray(sigs), gid_g).items()}
 
-from repro.compat import set_mesh_compat
 mesh = jax.make_mesh((4, 2), ("data", "model"))
 gid_local = (gid_g % (len(db) // 4)).astype(np.int32)
 for prededup in (False, True):
     step = make_mining_step(mesh, k=1024, db_axes=("data",),
                             tok_axis="model", prededup=prededup)
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         uniq, counts, n_distinct = step(
             jnp.asarray(tdb.tokens), jnp.asarray(gid_local), jnp.asarray(phi),
             jnp.asarray(psi), jnp.asarray(valid), jnp.asarray(existing),

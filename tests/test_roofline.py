@@ -3,6 +3,7 @@ workloads (scan trip counts, nested scans, dus windows, collectives)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.roofline.analysis import Roofline, parse_collectives
 from repro.roofline.hlo_cost import analyze
@@ -58,13 +59,20 @@ def test_walker_dus_window_not_full_buffer():
 def test_roofline_terms_and_bottleneck():
     r = Roofline(flops_per_chip=197e12, hbm_bytes_per_chip=819e9 / 2,
                  collective_bytes_per_chip=50e9 * 2, n_chips=4,
-                 model_flops=4 * 197e12 / 2)
+                 device_kind="TPU v5 lite", model_flops=4 * 197e12 / 2)
     assert abs(r.t_compute - 1.0) < 1e-9
     assert abs(r.t_memory - 0.5) < 1e-9
     assert abs(r.t_collective - 2.0) < 1e-9
     assert r.bottleneck == "collective"
     assert abs(r.useful_flops_ratio - 0.5) < 1e-9
     assert abs(r.roofline_fraction - 0.25) < 1e-9
+
+
+def test_roofline_unknown_device_kind_raises():
+    with pytest.raises(ValueError, match="no published peaks"):
+        Roofline(flops_per_chip=1.0, hbm_bytes_per_chip=1.0,
+                 collective_bytes_per_chip=0.0, n_chips=1,
+                 device_kind="cpu")
 
 
 def test_parse_collectives_from_text():
@@ -91,9 +99,8 @@ def test_walker_counts_collectives_inside_scans():
 
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map_compat
-
-    fn = jax.jit(shard_map_compat(f, mesh, P(), P()))
+    fn = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
+                               check_vma=False))
     x = jax.ShapeDtypeStruct((256,), jnp.float32)
     res = analyze(fn.lower(x).compile().as_text())
     # 7 trips x 1KB all-reduce (may be optimized away on 1 device; accept

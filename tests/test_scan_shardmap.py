@@ -1,13 +1,11 @@
-"""Gated repro for the jax<0.5 lax.scan-inside-shard_map miscompile.
+"""Repro for the lax.scan-inside-shard_map miscompile seen on jax 0.4.
 
-``repro.serving.batch`` unrolls its step loop because the scan +
-shard_map combination drops matches on the jax 0.4 CPU backend
-(containment comes out *lower* on non-zero data/model shards; the same
-scan unsharded and the same shard_map unrolled both agree with the
-oracle).  This test is the living record of that decision: it is
-skip-marked while the pinned jax is <0.5 and activates on upgrade - if
-it then passes, the unrolled loops in batch.py can be re-evaluated as a
-``lax.scan`` (smaller jit programs, faster trace) per the ROADMAP item.
+``repro.serving.batch`` unrolls its step loop because, on the jax 0.4
+CPU backend, the scan + shard_map combination dropped matches
+(containment came out *lower* on non-zero data/model shards; the same
+scan unsharded and the same shard_map unrolled both agreed with the
+oracle).  On the installed jax the two agree; this test keeps checking
+that, so a future change of the loop to ``lax.scan`` rests on it.
 
 The repro runs in a subprocess so the 8-fake-device XLA_FLAGS override
 cannot leak into the suite's single-device processes.
@@ -16,10 +14,7 @@ import os
 import subprocess
 import sys
 
-import jax
 import pytest
-
-_JAX_VERSION = tuple(int(x) for x in jax.__version__.split(".")[:2])
 
 REPRO_SCRIPT = r"""
 import os
@@ -33,7 +28,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 from conftest import random_db
-from repro.compat import shard_map_compat
 from repro.mining.driver import AcceleratedMiner
 from repro.mining.encoding import encode_db, PAD_PHI, PAD_PSI
 from repro.serving.bank import compile_bank
@@ -104,9 +98,9 @@ specs_out = (P("data", "model"), P("data", "model"))
 args = (tok, jnp.asarray(bank.steps), jnp.asarray(bank.pattern_valid))
 got = {}
 for scan in (False, True):
-    f = shard_map_compat(
-        functools.partial(dense_join, scan=scan), mesh,
-        specs_in, specs_out,
+    f = jax.shard_map(
+        functools.partial(dense_join, scan=scan), mesh=mesh,
+        in_specs=specs_in, out_specs=specs_out, check_vma=False,
     )
     c, o = jax.jit(f)(*args)
     got[scan] = np.asarray(c)
@@ -126,12 +120,6 @@ else:
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(
-    _JAX_VERSION < (0, 5),
-    reason="known-bad on jax<0.5 CPU: lax.scan inside shard_map drops "
-           "matches (hence the unrolled step loop in serving/batch.py);"
-           " re-evaluate when the jax pin moves",
-)
 def test_scan_inside_shard_map_matches_unrolled():
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
