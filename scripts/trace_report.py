@@ -10,8 +10,10 @@ minus its nested children), so nesting never double-counts:
 * ``host``     - python/numpy bookkeeping (encode, finalize, ring
                  upkeep, routing/merge logic)
 * ``dispatch`` - jax launch cost (the async call returning)
-* ``device``   - blocked device execution (``block_until_ready`` /
-                 host transfers)
+* ``device``   - spans a caller marks ``cat="device"``; the program
+                 records none (its launch spans end when the async call
+                 returns, and device time is read from the JAX
+                 profiler's device planes, where its spans also land)
 * ``cache``    - fingerprint + L1/L2 cache resolution
 * root spans (``cat="wall"``) define the denominator; their own self
   time is reported as *(uninstrumented)* - the honesty line: gaps the

@@ -32,7 +32,20 @@ resume re-enqueues them - supports are per-subtree and idempotent.
 Device timing: jax dispatch is async, so the launch and the execution
 are timed separately - ``dispatch_seconds`` stops when the call
 returns (launch cost only), ``device_seconds`` after
-``block_until_ready()`` (the real device time).
+``block_until_ready()`` (launch plus the wait; the host reads the
+signatures right after in any case).
+
+Host phases (``repro.obs.trace`` spans, which reach the JAX profiler's
+trace whenever a session runs): ``mining.pack`` (stacks and embedding
+encoding, once per slice), ``mining.scan`` (one chunk's dispatch and
+wait), ``mining.aggregate`` (its signatures read back and merged),
+and per item ``mining.children`` around ``mining.canonical`` (the
+signature -> extension -> canonical-form grouping), ``mining.parent``
+(the reverse-search membership test, once per frequent candidate) and
+``mining.rebuild`` (one kept child's embeddings).  The counters
+``candidates``, ``infrequent`` and ``rs_rejected`` count the funnel
+those phases walk: distinct canonical children, those below sigma,
+and those whose parent is another pattern.
 """
 from __future__ import annotations
 
@@ -122,6 +135,14 @@ class AcceleratedMiner:
             f"{metrics_ns}.n_device_calls")
         self._h_wave = self.metrics.histogram(
             f"{metrics_ns}.wave_patterns")
+        # the reverse-search funnel: distinct canonical children, those
+        # below sigma, and those that failed ``parent(child) == pattern``
+        self._c_candidates = self.metrics.counter(
+            f"{metrics_ns}.candidates")
+        self._c_infrequent = self.metrics.counter(
+            f"{metrics_ns}.infrequent")
+        self._c_rs_rejected = self.metrics.counter(
+            f"{metrics_ns}.rs_rejected")
         # always-on latency percentiles: wall (launch + blocked) per
         # packed device chunk, log-scale buckets
         self._h_wave_s = self.metrics.bucket_histogram(
@@ -165,86 +186,86 @@ class AcceleratedMiner:
         local to the item's embedding list, plus the item's encoded row
         arrays for the vectorized embedding rebuild."""
         n = len(items)
-        n_pad = _pow2_pad(n)
-        nv_stack = np.zeros(n_pad, np.int32)
-        npat_stack = np.zeros(n_pad, np.int32)
-        mode_stack = np.zeros(n_pad, np.int32)
-        ex_stack = np.full((n_pad, MAX_PATTERN_TRS, 5), -9, np.int32)
-        for i, (pattern, _) in enumerate(items):
-            nv_stack[i] = len(pattern_vertices(pattern))
-            npat_stack[i] = len(pattern)
-            mode_stack[i] = modes[i]
-            ex_stack[i] = encode_pattern_trs(pattern, MAX_PATTERN_TRS)
-        ex_j = jnp.asarray(ex_stack)
-        nv_j = jnp.asarray(nv_stack)
-        npat_j = jnp.asarray(npat_stack)
-        mode_j = jnp.asarray(mode_stack)
-
-        enc: List[Enc] = [
-            encode_embeddings(embs, self.ni, self.nv)
-            for _, embs in items
-        ]
-        lens = np.asarray([len(embs) for _, embs in items], np.int64)
-        offs = np.cumsum(lens) - lens
-        R = int(lens.sum())
         merged: List[Dict[int, Tuple[Set[int], List[np.ndarray]]]] = [
             {} for _ in items
         ]
-        if R == 0:
-            return merged, enc
-        gid_all = np.concatenate([e[0] for e in enc])
-        phi_all = np.concatenate([e[1] for e in enc])
-        psi_all = np.concatenate([e[2] for e in enc])
-        pid_all = np.repeat(np.arange(n, dtype=np.int32), lens)
+        with trace.span("mining.pack", patterns=n):
+            n_pad = _pow2_pad(n)
+            nv_stack = np.zeros(n_pad, np.int32)
+            npat_stack = np.zeros(n_pad, np.int32)
+            mode_stack = np.zeros(n_pad, np.int32)
+            ex_stack = np.full((n_pad, MAX_PATTERN_TRS, 5), -9, np.int32)
+            for i, (pattern, _) in enumerate(items):
+                nv_stack[i] = len(pattern_vertices(pattern))
+                npat_stack[i] = len(pattern)
+                mode_stack[i] = modes[i]
+                ex_stack[i] = encode_pattern_trs(pattern, MAX_PATTERN_TRS)
+            ex_j = jnp.asarray(ex_stack)
+            nv_j = jnp.asarray(nv_stack)
+            npat_j = jnp.asarray(npat_stack)
+            mode_j = jnp.asarray(mode_stack)
+
+            enc: List[Enc] = [
+                encode_embeddings(embs, self.ni, self.nv)
+                for _, embs in items
+            ]
+            lens = np.asarray([len(embs) for _, embs in items], np.int64)
+            offs = np.cumsum(lens) - lens
+            R = int(lens.sum())
+            if R == 0:
+                return merged, enc
+            gid_all = np.concatenate([e[0] for e in enc])
+            phi_all = np.concatenate([e[1] for e in enc])
+            psi_all = np.concatenate([e[2] for e in enc])
+            pid_all = np.repeat(np.arange(n, dtype=np.int32), lens)
 
         for start in range(0, R, self.e_batch):
             E = min(self.e_batch, R - start)
             Epad = _pow2_pad(E, cap=self.e_batch)
-            sl = slice(start, start + E)
-            gid = gid_all[sl]
-            phi = phi_all[sl]
-            psi = psi_all[sl]
-            pid = pid_all[sl]
-            if Epad > E:
-                gid = np.pad(gid, (0, Epad - E))
-                phi = np.pad(phi, ((0, Epad - E), (0, 0)),
-                             constant_values=PAD_PHI)
-                psi = np.pad(psi, ((0, Epad - E), (0, 0)),
-                             constant_values=PAD_PSI)
-                pid = np.pad(pid, (0, Epad - E))
-            valid = np.zeros((Epad,), np.int32)
-            valid[:E] = 1
-            t0 = time.perf_counter()
-            sigs = match_signatures_batch(
-                self.tokens,
-                jnp.asarray(gid), jnp.asarray(phi), jnp.asarray(psi),
-                jnp.asarray(valid), jnp.asarray(pid),
-                ex_j, nv_j, npat_j, mode_j,
-            )
-            t1 = time.perf_counter()
-            self._c_dispatch_s.inc(t1 - t0)
-            sigs.block_until_ready()  # async dispatch: launch != done
-            t2 = time.perf_counter()
-            self._c_device_s.inc(t2 - t0)
-            self._c_calls.inc()
-            self._h_wave_s.observe(t2 - t0)
-            # intervals are measured above regardless of tracing, so
-            # recording them cannot perturb the timing they describe
-            trace.add_complete("mining.dispatch", "dispatch",
-                               t0, t1 - t0, rows=int(Epad))
-            trace.add_complete("mining.device", "device", t1, t2 - t1)
-            for (pi, sig), (gset, et) in aggregate_host_batch(
-                np.asarray(sigs), gid, pid
-            ).items():
-                et = et.copy()
-                # chunk-local row -> this item's embedding index
-                et[:, 0] += start - offs[pi]
-                got = merged[pi].get(sig)
-                if got is None:
-                    merged[pi][sig] = (gset, [et])
-                else:
-                    got[0].update(gset)
-                    got[1].append(et)
+            with trace.span("mining.scan", rows=Epad):
+                sl = slice(start, start + E)
+                gid = gid_all[sl]
+                phi = phi_all[sl]
+                psi = psi_all[sl]
+                pid = pid_all[sl]
+                if Epad > E:
+                    gid = np.pad(gid, (0, Epad - E))
+                    phi = np.pad(phi, ((0, Epad - E), (0, 0)),
+                                 constant_values=PAD_PHI)
+                    psi = np.pad(psi, ((0, Epad - E), (0, 0)),
+                                 constant_values=PAD_PSI)
+                    pid = np.pad(pid, (0, Epad - E))
+                valid = np.zeros((Epad,), np.int32)
+                valid[:E] = 1
+                t0 = time.perf_counter()
+                sigs = match_signatures_batch(
+                    self.tokens,
+                    jnp.asarray(gid), jnp.asarray(phi), jnp.asarray(psi),
+                    jnp.asarray(valid), jnp.asarray(pid),
+                    ex_j, nv_j, npat_j, mode_j,
+                )
+                t1 = time.perf_counter()
+                self._c_dispatch_s.inc(t1 - t0)
+                # async dispatch: launch != done; the host reads the
+                # signatures right after, so this wait costs nothing
+                sigs.block_until_ready()
+                t2 = time.perf_counter()
+                self._c_device_s.inc(t2 - t0)
+                self._c_calls.inc()
+                self._h_wave_s.observe(t2 - t0)
+            with trace.span("mining.aggregate", rows=Epad):
+                for (pi, sig), (gset, et) in aggregate_host_batch(
+                    np.asarray(sigs), gid, pid
+                ).items():
+                    et = et.copy()
+                    # chunk-local row -> this item's embedding index
+                    et[:, 0] += start - offs[pi]
+                    got = merged[pi].get(sig)
+                    if got is None:
+                        merged[pi][sig] = (gset, [et])
+                    else:
+                        got[0].update(gset)
+                        got[1].append(et)
         return merged, enc
 
     # -------------------------------------------------- embedding rebuild
@@ -332,31 +353,43 @@ class AcceleratedMiner:
         want_embs: Optional[Callable[[Pattern], bool]],
     ) -> List[Tuple[Pattern, Set[int], List[Emb]]]:
         by_child: Dict[Pattern, Tuple[Set[int], int, List[np.ndarray]]] = {}
-        for sig, (gset, et_rows) in merged.items():
-            key = signature_to_extkey(sig)
-            if max(key[1].u1, key[1].u2) >= self.nv:
-                continue  # vertex-capacity guard
-            child_raw = apply_extension(pattern, key)
-            child = canonical_form(child_raw)
-            if child in by_child:
-                by_child[child][0].update(gset)
-            else:
-                by_child[child] = (set(gset), sig, et_rows)
+        with trace.span("mining.canonical", signatures=len(merged)):
+            for sig, (gset, et_rows) in merged.items():
+                key = signature_to_extkey(sig)
+                if max(key[1].u1, key[1].u2) >= self.nv:
+                    continue  # vertex-capacity guard
+                child_raw = apply_extension(pattern, key)
+                child = canonical_form(child_raw)
+                if child in by_child:
+                    by_child[child][0].update(gset)
+                else:
+                    by_child[child] = (set(gset), sig, et_rows)
         out: List[Tuple[Pattern, Set[int], List[Emb]]] = []
+        infrequent = rejected = 0
         for child, (gids, sig, et_rows) in by_child.items():
             if len(gids) < min_support:
+                infrequent += 1
                 continue
-            if rs and parent(child) != pattern:
-                continue  # reverse-search membership test
+            if rs:
+                # reverse-search membership test
+                with trace.span("mining.parent"):
+                    is_child = parent(child) == pattern
+                if not is_child:
+                    rejected += 1
+                    continue
             if want_embs is not None and not want_embs(child):
                 out.append((child, gids, []))
                 continue
-            key = signature_to_extkey(sig)
-            child_raw = apply_extension(pattern, key)
-            child_embs = self._rebuild_embeddings(
-                pattern, enc, sig, et_rows, child_raw
-            )
+            with trace.span("mining.rebuild"):
+                key = signature_to_extkey(sig)
+                child_raw = apply_extension(pattern, key)
+                child_embs = self._rebuild_embeddings(
+                    pattern, enc, sig, et_rows, child_raw
+                )
             out.append((child, gids, child_embs))
+        self._c_candidates.inc(len(by_child))
+        self._c_infrequent.inc(infrequent)
+        self._c_rs_rejected.inc(rejected)
         return out
 
     def expand_children_batch(
@@ -385,9 +418,10 @@ class AcceleratedMiner:
         modes = [self._phase_mode(p, rs) for _, p, _ in live]
         merged, enc = self._scan_batch([(p, e) for _, p, e in live], modes)
         for (i, p, _), m, enc_i in zip(live, merged, enc):
-            out[i] = self._children_from_merged(
-                p, enc_i, m, min_support, rs, want_embs
-            )
+            with trace.span("mining.children"):
+                out[i] = self._children_from_merged(
+                    p, enc_i, m, min_support, rs, want_embs
+                )
         return out
 
     def expand_children(

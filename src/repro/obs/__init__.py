@@ -15,20 +15,24 @@ cluster routing rounds - reports through this package:
                  a registry, so counters survive component rebuilds
                  and BENCH artifacts export a ``metrics`` block that
                  ``scripts/check_bench.py`` gates on.
-* ``trace``    - the span tracer: ``trace.span("serving.trie_level",
-                 cat="dispatch", level=k)`` regions bucketed into
-                 host / dispatch / device / cache, per-query and
+* ``trace``    - the span tracer: ``trace.span("serving.join",
+                 cat="dispatch", cells=n)`` regions bucketed into
+                 host / dispatch / cache, per-query and
                  per-wavefront trace ids threaded through
                  ``ClusterRouter.route -> ClusterHost.call ->
-                 PatternServer -> kernel dispatch`` by contextvar,
-                 Chrome-trace JSON + JSONL export.  Disabled by
-                 default with a property-tested no-op fast path; full
-                 ``enable()`` fences device spans, and the production
-                 mode ``enable_sampling(rate, latency_threshold=...)``
-                 keeps a deterministic fraction of root trees plus
-                 every tail-latency / ``mark()``-ed anomalous root,
-                 never fencing - results stay bit-identical and
-                 overhead inside the <= 5% budget.
+                 PatternServer -> kernel dispatch`` by contextvar.
+                 Two sinks: an in-memory buffer (full ``enable()``,
+                 or the production mode ``enable_sampling(rate,
+                 latency_threshold=...)`` keeping a deterministic
+                 fraction of root trees plus every tail-latency /
+                 ``mark()``-ed anomalous root) with Chrome-trace JSON
+                 + JSONL export, and - whenever a JAX profiler session
+                 is running - ``jax.profiler.TraceAnnotation``s on the
+                 profiler's host plane, on the device trace's clock.
+                 Neither sink blocks on the device; with no session
+                 and the buffer disabled a span is a shared no-op.
+                 Results stay bit-identical in every mode
+                 (property-tested).
 * ``flight``   - ``FlightRecorder``: a ring buffer of the last N kept
                  query span-trees + prefix-scoped metric deltas,
                  dumped to JSONL on demand, on anomaly, or by the

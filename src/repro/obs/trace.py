@@ -1,16 +1,19 @@
 """Span tracer: phase-attributed wall time across mine/serve/stream/
-cluster, exported as Chrome-trace JSON or JSONL.
+cluster, with two sinks - an in-memory buffer exported as Chrome-trace
+JSON or JSONL, and the JAX profiler's own trace.
 
-The repo's performance questions ("where did the H4 cluster qps go?")
-need wall time *attributed*: how much of a routed drain was host
-bookkeeping vs kernel launch vs actual device execution vs cache
-lookups.  This module is that substrate:
+The repo's performance questions ("where did the H4 cluster qps go?",
+"which host phase keeps the device idle?") need wall time
+*attributed*: how much of a routed drain was host bookkeeping vs
+kernel launch vs cache lookups.  This module is that substrate:
 
-* ``span(name, cat=..., **args)`` - a context manager recording one
-  timed region.  ``cat`` is the attribution bucket (``"host"``,
-  ``"dispatch"``, ``"device"``, ``"cache"``); ``scripts/trace_report.py``
-  sums *self time* (duration minus nested child spans) per bucket, so
-  nesting never double-counts.
+* ``span(name, cat=..., **args)`` - a context manager around one
+  region.  ``cat`` is the attribution bucket (``"host"``,
+  ``"dispatch"``, ``"cache"``); ``scripts/trace_report.py`` sums *self
+  time* (duration minus nested child spans) per bucket, so nesting
+  never double-counts.  Names are dotted by layer (``mining.scan``,
+  ``serving.join``, ``cluster.flush``) and a span wraps one unit of
+  work (a batch, a chunk, a candidate), never one embedding.
 * ``root_or_span(name, **args)`` - public entry points
   (``ClusterRouter.route``, ``PatternServer.query``,
   ``StreamingBank.observe/refresh``, ``AcceleratedMiner.mine_rs``)
@@ -19,32 +22,37 @@ lookups.  This module is that substrate:
   query owns one trace id that threads through
   ``ClusterRouter.route -> ClusterHost.call -> PatternServer ->
   kernel dispatch`` via a contextvar, with zero plumbing in signatures.
-* ``add_complete(name, cat, start, duration)`` - record an
-  already-measured interval (the miner times dispatch vs
-  ``block_until_ready()`` with its own ``perf_counter`` pairs; the
-  tracer must not perturb that measurement).
 
-**Disabled is the default and the fast path**: ``span()`` returns a
-shared no-op context manager, nothing is recorded, no clocks are read,
-and - property-tested in tests/test_obs.py - results and device
-dispatch counts are bit-identical with tracing on, off, or absent.
-Tracing only ever *observes*: the one behavioural difference when
-fully enabled is extra ``block_until_ready()`` fences inside device
-spans (needed to split launch from execution time; they change timing,
-never results or dispatch counts).
+**The profiler sink.**  Whenever a JAX profiler session is running -
+``jax.profiler.start_trace``, ``jax.profiler.trace`` or a capture
+through ``jax.profiler.start_server`` - every span (and every root)
+also opens a ``jax.profiler.TraceAnnotation`` of the same name, its
+keyword arguments passed as metadata.  The spans then land on the
+profiler's host plane on the same clock as the device's ``XLA Ops``,
+so idle stretches of the device can be named by the host phase that
+kept it waiting.  A running session is the only switch: the test is
+``TraceAnnotation.is_enabled()``, one C++ call.
+
+**Disabled is the default and the fast path**: with no profiler
+session and the buffer disabled, ``span()`` returns a shared no-op
+context manager, nothing is recorded, no clocks are read, and -
+property-tested in tests/test_obs.py - results and device dispatch
+counts are bit-identical with tracing on, off, or absent.  Tracing only
+ever *observes*: no mode blocks on the device.  Launch spans
+(``cat="dispatch"``) end when the async call returns; where the device
+time went is read from the profiler's device planes, not from host
+fences.
 
 **Sampled mode** (``enable_sampling(rate, ...)``) is the always-on
-production middle ground.  A deterministic systematic sampler (an
-accumulator, no RNG - reproducible run to run) keeps roughly
-``rate`` of root spans with their full child trees; the rest become
-*tail* roots: two clock reads and nothing recorded, unless the query
-breaches ``latency_threshold`` or a layer flagged it anomalous via
-``mark()`` (shed, ``exact=False``, overflow escalation), in which case
-the root span is kept with ``tail=True``.  Sampled mode NEVER fences:
-``server._fence`` consults ``fencing()`` and records the dispatch half
-only, so the async pipeline (PR 7/8) keeps its overlap - which is why
-sampled results stay bit-identical and overhead stays within the <= 5%
-budget ``check_bench.py`` gates.  Kept traces are counted
+production middle ground for the buffer.  A deterministic systematic
+sampler (an accumulator, no RNG - reproducible run to run) keeps
+roughly ``rate`` of root spans with their full child trees; the rest
+become *tail* roots: two clock reads and nothing recorded, unless the
+query breaches ``latency_threshold`` or a layer flagged it anomalous
+via ``mark()`` (shed, ``exact=False``, overflow escalation), in which
+case the root span is kept with ``tail=True``.  Results stay
+bit-identical and overhead stays within the <= 5% budget
+``check_bench.py`` gates.  Kept traces are counted
 (``obs.sampled_spans`` / ``obs.sampled_traces`` / ``obs.tail_traces``
 in the registry passed to ``enable_sampling``) and fed to the optional
 ``FlightRecorder``.
@@ -60,6 +68,8 @@ import json
 import time
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 # attribution buckets trace_report.py understands; "wall" is reserved
 # for root spans (their duration IS the denominator of the report)
@@ -84,9 +94,19 @@ class _NoopSpan:
 
 _NOOP = _NoopSpan()
 
+# True while a JAX profiler session is running (one C++ call)
+_profiling = TraceAnnotation.is_enabled
+
+
+def _annotation(name: str, args: Dict[str, Any]):
+    """The profiler sink: a ``TraceAnnotation`` on the profiler's host
+    plane while a session runs, the shared no-op otherwise."""
+    return TraceAnnotation(name, **args) if _profiling() else _NOOP
+
 
 class _Span:
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_token")
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_token",
+                 "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: Dict[str, Any], new_trace: bool):
@@ -99,13 +119,16 @@ class _Span:
             _current_trace.set(tracer._next_trace_id())
             if new_trace else None
         )
+        self._ann = _annotation(name, args)
         self._t0 = tracer.clock()
 
     def __enter__(self) -> "_Span":
+        self._ann.__enter__()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = self._tracer.clock()
+        self._ann.__exit__(*exc)
         self._tracer._record(
             self.name, self.cat, self._t0, t1 - self._t0, self.args
         )
@@ -129,11 +152,10 @@ class SamplingConfig:
 class _SampledRoot:
     """A root span whose whole child tree is recorded.  Temporarily
     flips ``tracer.enabled`` so nested ``span()`` calls record (the
-    serving stack is single-threaded; the flag is restored on exit),
-    WITHOUT setting ``_full`` - so ``_fence`` stays async."""
+    serving stack is single-threaded; the flag is cleared on exit)."""
 
     __slots__ = ("_tracer", "name", "args", "_t0", "_token", "_ev0",
-                 "anomaly")
+                 "anomaly", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str,
                  args: Dict[str, Any]):
@@ -145,21 +167,24 @@ class _SampledRoot:
         self._ev0 = len(tracer.events)
         tracer.enabled = True
         tracer._root = self
+        self._ann = _annotation(name, args)
         self._t0 = tracer.clock()
 
     def __enter__(self) -> "_SampledRoot":
+        self._ann.__enter__()
         return self
 
     def __exit__(self, *exc) -> bool:
         tr = self._tracer
         t1 = tr.clock()
+        self._ann.__exit__(*exc)
         dur = t1 - self._t0
         args = dict(self.args)
         if self.anomaly:
             args["anomaly"] = self.anomaly
         tr._record(self.name, "wall", self._t0, dur, args)
         spans = tr.events[self._ev0:]
-        tr.enabled = tr._full
+        tr.enabled = False
         tr._root = None
         trace_id = _current_trace.get()
         _current_trace.reset(self._token)
@@ -173,7 +198,8 @@ class _TailRoot:
     entry points stay no-ops, and a record only if the root breached
     the latency threshold or was ``mark()``-ed anomalous."""
 
-    __slots__ = ("_tracer", "name", "args", "_t0", "_token", "anomaly")
+    __slots__ = ("_tracer", "name", "args", "_t0", "_token", "anomaly",
+                 "_ann")
 
     def __init__(self, tracer: "Tracer", name: str,
                  args: Dict[str, Any]):
@@ -183,14 +209,17 @@ class _TailRoot:
         self.anomaly: Optional[str] = None
         self._token = _current_trace.set(tracer._next_trace_id())
         tracer._root = self
+        self._ann = _annotation(name, args)
         self._t0 = tracer.clock()
 
     def __enter__(self) -> "_TailRoot":
+        self._ann.__enter__()
         return self
 
     def __exit__(self, *exc) -> bool:
         tr = self._tracer
         t1 = tr.clock()
+        self._ann.__exit__(*exc)
         dur = t1 - self._t0
         s = tr.sampling
         thr = s.latency_threshold if s is not None else None
@@ -232,7 +261,6 @@ class Tracer:
         self._trace_seq = 0
         # sampled-mode state
         self.sampling: Optional[SamplingConfig] = None
-        self._full = False     # True only under enable(): fences on
         self._acc = 0.0        # systematic-sampler accumulator
         self._root = None      # active sampled/tail root (mark target)
         self.metrics = None    # Optional[MetricsRegistry]
@@ -240,9 +268,8 @@ class Tracer:
 
     # ------------------------------------------------------- lifecycle
     def enable(self) -> None:
-        """Full tracing: every span recorded, device spans fenced."""
+        """Full tracing: every span recorded."""
         self.enabled = True
-        self._full = True
         self.sampling = None
         if not self.events:
             self._t_base = self.clock()
@@ -251,7 +278,7 @@ class Tracer:
                         latency_threshold: Optional[float] = None,
                         metrics=None, flight=None) -> None:
         """Always-on mode: keep ~``rate`` of root-span trees plus every
-        tail/anomalous root, never fence.  ``metrics`` (a
+        tail/anomalous root.  ``metrics`` (a
         ``MetricsRegistry``) receives the ``obs.*`` keep counters;
         ``flight`` (a ``FlightRecorder``) receives kept traces."""
         self.sampling = SamplingConfig(
@@ -261,13 +288,11 @@ class Tracer:
         self.metrics = metrics
         self.flight = flight
         self.enabled = False
-        self._full = False
         if not self.events:
             self._t_base = self.clock()
 
     def disable(self) -> None:
         self.enabled = False
-        self._full = False
         self.sampling = None
         self._root = None
         self.metrics = None
@@ -318,14 +343,6 @@ class Tracer:
             self.flight.record(name, dur, spans, anomaly=anomaly,
                                kind=kind, trace=trace_id)
 
-    def add_complete(self, name: str, cat: str, start: float,
-                     duration: float, **args: Any) -> None:
-        """Record an interval measured by the caller (``start`` is a
-        ``time.perf_counter()`` value, so it nests consistently with
-        context-manager spans)."""
-        if self.enabled:
-            self._record(name, cat, start, duration, args)
-
     # --------------------------------------------------------- export
     def chrome_events(self) -> List[Dict[str, Any]]:
         out = []
@@ -358,14 +375,6 @@ tracer = Tracer()
 
 def enabled() -> bool:
     return tracer.enabled
-
-
-def fencing() -> bool:
-    """True only under full tracing (``enable()``): device spans may
-    ``block_until_ready`` to split launch from execution.  Sampled mode
-    returns False - the fence would serialize the async pipeline, so
-    sampled traces record the dispatch half only."""
-    return tracer._full
 
 
 def sampling() -> Optional[SamplingConfig]:
@@ -411,11 +420,12 @@ def current_trace() -> Optional[int]:
 
 
 def span(name: str, cat: str = "host", **args: Any):
-    """A timed region attributed to bucket ``cat``.  No-op (shared
-    singleton, no clock read) while tracing is disabled."""
-    if not tracer.enabled:
-        return _NOOP
-    return _Span(tracer, name, cat, args, new_trace=False)
+    """A timed region attributed to bucket ``cat``.  With the buffer
+    disabled it is the profiler's annotation while a session runs, and
+    otherwise the shared no-op (no clock read)."""
+    if tracer.enabled:
+        return _Span(tracer, name, cat, args, new_trace=False)
+    return _annotation(name, args)
 
 
 def root_or_span(name: str, **args: Any):
@@ -433,14 +443,10 @@ def root_or_span(name: str, **args: Any):
         return _Span(tracer, name, "host", args, new_trace=False)
     s = tracer.sampling
     if s is None or _current_trace.get() is not None:
-        return _NOOP
+        return _annotation(name, args)
     tracer._acc += s.rate
     if tracer._acc >= 1.0:
         tracer._acc -= 1.0
         return _SampledRoot(tracer, name, args)
     return _TailRoot(tracer, name, args)
 
-
-def add_complete(name: str, cat: str, start: float, duration: float,
-                 **args: Any) -> None:
-    tracer.add_complete(name, cat, start, duration, **args)

@@ -175,9 +175,20 @@ misses); per-shard servers count every join entry point under
 ``serving.server.h<hid>.queries``.  The span tracer (``repro.obs.trace``)
 threads one trace id per routed query / wavefront through
 ``ClusterRouter.route -> ClusterHost.call -> PatternServer -> kernel
-dispatch``, splitting launch from blocked device time; it is off by
-default and property-tested to change nothing (tests/test_obs.py).
-Render a saved trace with ``scripts/trace_report.py``.
+dispatch``; it is off by default and property-tested to change nothing
+(tests/test_obs.py).  Render a saved trace with
+``scripts/trace_report.py``.  While a JAX profiler session runs, the
+same spans also land on the profiler's host plane, on the device
+trace's clock: ``serving.fingerprint`` (the renaming-invariant
+fingerprints of a submit or route), ``cluster.flush`` with
+``serving.encode``, ``serving.token_index``, ``serving.batch``,
+``serving.prescreen_host`` and ``serving.join`` (launches: each ends
+when the async call returns), and ``cluster.fence`` with
+``serving.finalize_rows``, ``serving.readback`` (the layout's read of
+the join outputs), ``serving.escalate`` (the escalation ladder) and
+``serving.oracle`` (the host fallback inside it).  No mode blocks on
+the device to time it: device time is read from the profiler's device
+planes.
 
 **Always-on production telemetry** (cheap enough to leave on; the
 benches gate the measured sampled-mode overhead <= 5%):
@@ -196,9 +207,9 @@ benches gate the measured sampled-mode overhead <= 5%):
   ``1/rate``-th root span *tree* (deterministic systematic sampler -
   no RNG, so results stay bit-reproducible) plus every tail root that
   breaches ``latency_threshold`` or was ``trace.mark()``-ed anomalous
-  (shed, inexact, overflow-escalated).  Unlike full ``enable()``,
-  sampled mode never fences - device spans record launch only, so the
-  async pipeline keeps its overlap.  Keeps count under
+  (shed, inexact, overflow-escalated).  Like full ``enable()`` it
+  never blocks on the device - launch spans end when the call
+  returns, so the async pipeline keeps its overlap.  Keeps count under
   ``obs.{sampled_spans, sampled_traces, tail_traces}``.
 * **Flight recorder** - ``FlightRecorder`` rings the last N kept
   traces with per-entry metric deltas; dumped as JSONL on demand, on

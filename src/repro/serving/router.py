@@ -579,9 +579,10 @@ class ClusterRouter:
             with trace.span("cluster.cache", cat="cache"):
                 for hid, seqs in requests.items():
                     host = self.hosts[hid]
-                    fps[hid] = hfps = [
-                        sequence_fingerprint(s) for s in seqs
-                    ]
+                    with trace.span("serving.fingerprint", n=len(seqs)):
+                        fps[hid] = hfps = [
+                            sequence_fingerprint(s) for s in seqs
+                        ]
                     self.stats["queries"] += len(seqs)
                     for fp, s in zip(hfps, seqs):
                         arrival_hosts.setdefault(fp, set()).add(hid)
@@ -707,9 +708,10 @@ class ClusterRouter:
             with trace.span("cluster.cache", cat="cache"):
                 for hid, seqs in requests.items():
                     host = self.hosts[hid]
-                    ticket.fps[hid] = hfps = [
-                        sequence_fingerprint(s) for s in seqs
-                    ]
+                    with trace.span("serving.fingerprint", n=len(seqs)):
+                        ticket.fps[hid] = hfps = [
+                            sequence_fingerprint(s) for s in seqs
+                        ]
                     self.stats["queries"] += len(seqs)
                     for fp, s in zip(hfps, seqs):
                         ticket.arrival_hosts.setdefault(

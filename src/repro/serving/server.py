@@ -165,26 +165,6 @@ class QueryResult:
         return np.nonzero(self.contained)[0]
 
 
-def _fence(name: str, t0: float, out, **args) -> None:
-    """Tracing-only launch/execution split for one async device call:
-    under *full* tracing, fence the dispatch and record both halves.
-    Under sampled tracing (``trace.fencing()`` is False) record the
-    dispatch half only - a fence here would serialize the async
-    pipeline the sampler exists to observe, so sampled traces carry
-    launch time and the device half is attributed at the existing
-    finalize fences.  When off this returns before reading any clock -
-    the disabled path never blocks, so results, dispatch counts, and
-    async overlap are untouched."""
-    if not trace.enabled():
-        return
-    t1 = time.perf_counter()
-    trace.add_complete(name, "dispatch", t0, t1 - t0, **args)
-    if trace.fencing():
-        jax.block_until_ready(out)
-        t2 = time.perf_counter()
-        trace.add_complete(name + ".device", "device", t1, t2 - t1)
-
-
 @dataclasses.dataclass
 class SharedEncoding:
     """Query-side device encoding shared across bank shards.
@@ -234,11 +214,10 @@ def encode_queries(
         )
         tokens = jnp.asarray(tdb.tokens)
         tmax = _pow2(max_key_bucket(tdb.tokens, n_label_keys))
-    t0 = time.perf_counter()
-    order, start, count = token_index(
-        tokens, n_label_keys=n_label_keys
-    )
-    _fence("serving.token_index", t0, (order, start, count))
+    with trace.span("serving.token_index", cat="dispatch"):
+        order, start, count = token_index(
+            tokens, n_label_keys=n_label_keys
+        )
     counts_np = np.asarray(count)
     return SharedEncoding(
         seqs=seqs, tokens=tokens, order=order, start=start,
@@ -533,7 +512,8 @@ class PatternServer:
         the old synchronous batch, bit for bit."""
         with trace.span("serving.finalize_rows", n=len(flight.seqs),
                         layout=flight.layout):
-            get_layout(flight.layout).finalize(self, flight)
+            with trace.span("serving.readback"):
+                get_layout(flight.layout).finalize(self, flight)
             self._resolve_undecided(
                 flight.tokens, flight.order, flight.start,
                 flight.count, flight.tmax, flight.contained,
@@ -603,12 +583,10 @@ class PatternServer:
         if shared is None:
             tokens, tmax = self._encode_own(seqs)
             # one index build per batch, shared by every group join
-            t0 = time.perf_counter()
-            order, start, count, possible = index_and_prescreen(
-                tokens, self._req, n_label_keys=bank.n_label_keys
-            )
-            _fence("serving.prescreen", t0,
-                   (order, start, count, possible))
+            with trace.span("serving.prescreen", cat="dispatch"):
+                order, start, count, possible = index_and_prescreen(
+                    tokens, self._req, n_label_keys=bank.n_label_keys
+                )
             possible = np.asarray(possible)[
                 : len(seqs), : bank.n_patterns]
         else:
@@ -648,16 +626,15 @@ class PatternServer:
             bi = np.zeros(npad, np.int32)
             pi = np.zeros(npad, np.int32)
             bi[:n], pi[:n] = b_idx, g_idx
-            t0 = time.perf_counter()
-            c, o = pair_contains_indexed(
-                tokens, order, start, count, steps_g,
-                jnp.asarray(bi), jnp.asarray(pi),
-                nv=bank.nv, emax=self.emax, tmax=tmax,
-                use_kernel=self.use_kernel, block_g=self.block_g,
-                uniform_length=True,
-            )
-            _fence("serving.join", t0, (c, o),
-                   steps=int(steps_g.shape[1]), cells=n)
+            with trace.span("serving.join", cat="dispatch",
+                            steps=int(steps_g.shape[1]), cells=n):
+                c, o = pair_contains_indexed(
+                    tokens, order, start, count, steps_g,
+                    jnp.asarray(bi), jnp.asarray(pi),
+                    nv=bank.nv, emax=self.emax, tmax=tmax,
+                    use_kernel=self.use_kernel, block_g=self.block_g,
+                    uniform_length=True,
+                )
             pending.append((b_idx, rows[g_idx], c, o, n))
         return InFlightRows(
             layout="flat", seqs=seqs, tokens=tokens, order=order,
@@ -697,17 +674,18 @@ class PatternServer:
             contained[:, ~self._row_mask] = False
             ovf[:, ~self._row_mask] = False
         bank = self.bank
-        if (ovf & ~contained).any():
-            # an always-keep signal for the tail sampler: escalated
-            # queries are the interesting ones
-            trace.mark("overflow_escalated")
-            if self.emax_retry > self.emax:
-                self.layout.escalate(self, tokens, order, start, count,
-                                     tmax, contained, ovf)
-        with trace.span("serving.oracle"):
-            for b, p in zip(*np.nonzero(ovf & ~contained)):
-                contained[b, p] = contains(bank.patterns[p], seqs[b])
-                self.stats["host_fallback_cells"] += 1
+        with trace.span("serving.escalate"):
+            if (ovf & ~contained).any():
+                # an always-keep signal for the tail sampler: escalated
+                # queries are the interesting ones
+                trace.mark("overflow_escalated")
+                if self.emax_retry > self.emax:
+                    self.layout.escalate(self, tokens, order, start,
+                                         count, tmax, contained, ovf)
+            with trace.span("serving.oracle"):
+                for b, p in zip(*np.nonzero(ovf & ~contained)):
+                    contained[b, p] = contains(bank.patterns[p], seqs[b])
+                    self.stats["host_fallback_cells"] += 1
 
     def _escalate_flat(self, tokens, order, start, count, tmax,
                        contained, ovf):
@@ -726,16 +704,15 @@ class PatternServer:
             bi = np.zeros(mpad, np.int32)
             pi = np.zeros(mpad, np.int32)
             bi[:m], pi[:m] = ub, self._row_pos[up]
-            t0 = time.perf_counter()
-            c2, o2 = pair_contains_indexed(
-                tokens, order, start, count, steps_g,
-                jnp.asarray(bi), jnp.asarray(pi),
-                nv=bank.nv, emax=self.emax_retry, tmax=tmax,
-                use_kernel=self.use_kernel, block_g=self.block_g,
-                uniform_length=True,
-            )
-            _fence("serving.escalate.join", t0, (c2, o2),
-                        cells=m)
+            with trace.span("serving.escalate.join", cat="dispatch",
+                            cells=m):
+                c2, o2 = pair_contains_indexed(
+                    tokens, order, start, count, steps_g,
+                    jnp.asarray(bi), jnp.asarray(pi),
+                    nv=bank.nv, emax=self.emax_retry, tmax=tmax,
+                    use_kernel=self.use_kernel, block_g=self.block_g,
+                    uniform_length=True,
+                )
             contained[ub, up] = np.asarray(c2)[:m]
             ovf[ub, up] = np.asarray(o2)[:m]
             self.stats["escalated_cells"] += m
@@ -782,22 +759,22 @@ class PatternServer:
             kw = dict(emax=self.emax_retry, tmax=tmax,
                       use_kernel=self.use_kernel, block_g=self.block_g,
                       compact=True)
-            t0 = time.perf_counter()
-            if d == 0:
-                out = trie_root_advance(
-                    tokens, order, start, count, jnp.asarray(cells),
-                    ni=len(self._tlevels), nv=bank.nv, **kw,
-                )
-            else:
+            if d:
                 par = pos_prev[b_idx, lv["parent_pos"][n_idx]]
                 assert (par >= 0).all(), "escalation path parent missing"
                 cells[:n_cells, 1] = par
-                out = trie_level_advance_gather(
-                    tokens, order, start, count, *prev,
-                    jnp.asarray(cells), **kw,
-                )
-            _fence("serving.escalate.trie_level", t0, out,
-                        level=d, cells=n_cells)
+            with trace.span("serving.escalate.trie_level",
+                            cat="dispatch", level=d, cells=n_cells):
+                if d == 0:
+                    out = trie_root_advance(
+                        tokens, order, start, count, jnp.asarray(cells),
+                        ni=len(self._tlevels), nv=bank.nv, **kw,
+                    )
+                else:
+                    out = trie_level_advance_gather(
+                        tokens, order, start, count, *prev,
+                        jnp.asarray(cells), **kw,
+                    )
             phi, psi, valid, acc, ovf_state, ovf_term = out
             prev = (phi, psi, valid, ovf_state)
             cell_pos = np.full((B0, len(lv["nodes"])), -1, np.int64)
@@ -851,12 +828,11 @@ class PatternServer:
             return flight()
         if shared is None:
             tokens, tmax = self._encode_own(seqs)
-            t0 = time.perf_counter()
-            order, start, count, possible = index_and_node_prescreen(
-                tokens, self._node_req, n_label_keys=bank.n_label_keys
-            )
-            _fence("serving.prescreen", t0,
-                   (order, start, count, possible))
+            with trace.span("serving.prescreen", cat="dispatch"):
+                order, start, count, possible = index_and_node_prescreen(
+                    tokens, self._node_req,
+                    n_label_keys=bank.n_label_keys,
+                )
             poss = np.asarray(possible)[:B0]
         else:
             assert shared.n_label_keys == bank.n_label_keys
@@ -893,23 +869,21 @@ class PatternServer:
             kw = dict(emax=self.emax, tmax=tmax,
                       use_kernel=self.use_kernel, block_g=self.block_g,
                       compact=compact)
-            t0 = time.perf_counter()
-            if d == 0:
-                out = trie_root_advance(
-                    tokens, order, start, count, jnp.asarray(cells),
-                    ni=D, nv=bank.nv, **kw,
-                )
-            else:
+            if d:
                 par = pos_prev[b_idx, lv["parent_pos"][n_idx]]
                 assert (par >= 0).all(), "parent cell pruned below child"
                 cells[:n, 1] = par
-                out = trie_level_advance_gather(
+            with trace.span("serving.trie_advance", cat="dispatch",
+                            level=d, cells=n):
+                if d == 0:
+                    return trie_root_advance(
+                        tokens, order, start, count, jnp.asarray(cells),
+                        ni=D, nv=bank.nv, **kw,
+                    )
+                return trie_level_advance_gather(
                     tokens, order, start, count, *prev,
                     jnp.asarray(cells), **kw,
                 )
-            _fence("serving.trie_advance", t0, out,
-                        level=d, cells=n)
-            return out
 
         for d, lv in enumerate(self._tlevels):
             act = poss[:, lv["nodes"]]
@@ -998,12 +972,11 @@ class PatternServer:
             return flight()
         if shared is None:
             tokens, tmax = self._encode_own(seqs)
-            t0 = time.perf_counter()
-            order, start, count, possible = index_and_node_prescreen(
-                tokens, self._node_req, n_label_keys=bank.n_label_keys
-            )
-            _fence("serving.prescreen", t0,
-                   (order, start, count, possible))
+            with trace.span("serving.prescreen", cat="dispatch"):
+                order, start, count, possible = index_and_node_prescreen(
+                    tokens, self._node_req,
+                    n_label_keys=bank.n_label_keys,
+                )
             poss = np.asarray(possible)[:B0]
         else:
             assert shared.n_label_keys == bank.n_label_keys
@@ -1048,14 +1021,13 @@ class PatternServer:
         cells = np.zeros((npad, 2), np.int32)
         cells[:n, 0] = b_idx
         cells[:n, 1] = s_idx
-        t0 = time.perf_counter()
-        acc, ovft = fused_trie_walk(
-            tokens, order, start, count, jnp.asarray(cells),
-            self._pk_steps, self._pk_parent, self._pk_req,
-            ni=len(self._tlevels), nv=bank.nv, emax=self.emax,
-            tmax=tmax, use_kernel=self.use_kernel,
-        )
-        _fence("serving.fused_walk", t0, (acc, ovft), cells=n)
+        with trace.span("serving.fused_walk", cat="dispatch", cells=n):
+            acc, ovft = fused_trie_walk(
+                tokens, order, start, count, jnp.asarray(cells),
+                self._pk_steps, self._pk_parent, self._pk_req,
+                ni=len(self._tlevels), nv=bank.nv, emax=self.emax,
+                tmax=tmax, use_kernel=self.use_kernel,
+            )
         cell_of = np.full((B0, pack.n_subtrees), -1, np.int64)
         cell_of[b_idx, s_idx] = np.arange(n)
         sub = cell_of[:, pack.term_sub]

@@ -232,3 +232,18 @@ def test_checkpoint_roundtrip(tmp_path):
     assert patterns == res.patterns
     assert stack2 == stack
     assert meta == {"x": 1}
+
+
+def test_reverse_search_funnel_counters():
+    """Every distinct canonical child is counted once and leaves the
+    funnel one way: below sigma, rejected by the reverse-search
+    parent test, or kept as a frequent pattern."""
+    db = random_db(2, n_seq=8, n_steps=4, n_v=4)
+    m = AcceleratedMiner(db)
+    res = m.mine_rs(2, max_len=4)
+    snap = m.metrics.snapshot("mining")
+    cand = snap["mining.candidates"]
+    assert cand == (snap["mining.infrequent"] + snap["mining.rs_rejected"]
+                    + res.n_enumerated)
+    assert snap["mining.rs_rejected"] > 0
+    assert snap["mining.infrequent"] > 0

@@ -5,18 +5,24 @@ Tracing is off by default and must be *free*: with the tracer
 disabled, every instrumented subsystem (mining wavefront, serving
 joins, streaming refreshes, cluster routing) must produce bit-identical
 results, identical device dispatch counts, and zero recorded events
-compared to the uninstrumented seed code; enabling tracing may add
-fences (it blocks to split launch from device time) but must never
-change a result either.  The registry's reset semantics are the other
+compared to the uninstrumented seed code; enabling the buffer or
+running a JAX profiler session (the tracer's second sink) must never
+change a result either, and no mode blocks on the device.  The
+registry's reset semantics are the other
 contract: counters live in the registry, so component rebuilds
 (``refresh(full=True)`` recompiling a server, the sharded-window
 protocol re-planning its router) accumulate instead of silently
 zeroing."""
+import contextlib
+import glob
 import json
 import os
+import shutil
 import sys
+import tempfile
 import time
 
+import jax
 import numpy as np
 import pytest
 from conftest import random_db
@@ -49,6 +55,51 @@ def _tracer_off():
     yield
     trace.disable()
     trace.clear()
+
+
+@contextlib.contextmanager
+def _profiled(log_dir=None):
+    """A JAX profiler session with the Python tracer off; its trace is
+    kept under ``log_dir`` (dropped when none is given)."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    path = log_dir or tempfile.mkdtemp(prefix="obs-profile-")
+    jax.profiler.start_trace(path, profiler_options=opts)
+    try:
+        yield path
+    finally:
+        jax.profiler.stop_trace()
+        if log_dir is None:
+            shutil.rmtree(path, ignore_errors=True)
+
+
+@contextlib.contextmanager
+def _traced(mode):
+    """Tracing on: the in-memory buffer (``"enable"``) or a profiler
+    session (``"profiler"``)."""
+    if mode == "enable":
+        trace.enable()
+        try:
+            yield
+        finally:
+            trace.disable()
+    else:
+        with _profiled():
+            yield
+
+
+def _host_events(log_dir):
+    """``{name: count}`` of the events on the profiler's host planes."""
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                            recursive=True))[-1]
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    out[e.name] = out.get(e.name, 0) + 1
+    return out
 
 
 def _spread(queries, n_hosts):
@@ -198,8 +249,54 @@ def test_bucket_histogram_single_value():
 def test_disabled_tracer_is_shared_noop():
     assert not trace.enabled()
     assert trace.span("x") is trace.span("y") is trace.root_or_span("z")
-    trace.add_complete("x", "device", 0.0, 1.0)
+    # categories and arguments change nothing on the fast path
+    assert trace.span("x", cat="dispatch", n=1) is trace.span("y")
+    with trace.span("x", cat="dispatch", n=1):
+        pass
     assert trace.tracer.events == []
+
+
+def test_profiler_session_turns_the_annotation_sink_on(tmp_path):
+    """A running profiler session is the only switch of the second
+    sink: spans become ``TraceAnnotation``s while it runs (the buffer
+    stays empty) and the shared no-op again once it stops."""
+    noop = trace.span("x")
+    with _profiled(str(tmp_path)) as path:
+        s = trace.span("t.leaf", cat="dispatch", n=3)
+        assert isinstance(s, jax.profiler.TraceAnnotation)
+        root = trace.root_or_span("t.root", n=1)
+        assert isinstance(root, jax.profiler.TraceAnnotation)
+        with root:
+            with s:
+                pass
+        # the buffer and the profiler together: one span, both sinks
+        trace.enable()
+        with trace.root_or_span("t.both"):
+            pass
+        trace.disable()
+    names = _host_events(path)
+    assert trace.span("y") is noop
+    assert names.get("t.root") == names.get("t.leaf") == 1
+    assert names.get("t.both") == 1
+    assert [e["name"] for e in trace.tracer.events] == ["t.both"]
+
+
+def test_sampled_roots_reach_the_profiler(tmp_path):
+    """Sampled and tail roots, and the spans under them, open their
+    annotations too."""
+    with _profiled(str(tmp_path)) as path:
+        trace.enable_sampling(1.0)
+        with trace.root_or_span("t.kept"):
+            with trace.span("t.kept_child"):
+                pass
+        trace.enable_sampling(0.0)
+        with trace.root_or_span("t.tail"):
+            with trace.span("t.tail_child"):
+                pass
+        trace.disable()
+    names = _host_events(path)
+    for n in ("t.kept", "t.kept_child", "t.tail", "t.tail_child"):
+        assert names.get(n) == 1, n
 
 
 def test_span_nesting_and_trace_ids():
@@ -273,11 +370,13 @@ def test_trace_report_rejects_malformed(tmp_path):
 
 # ========================================== no-op path: bit-identical
 @pytest.mark.slow
-@given(st.integers(0, 10_000))
+@pytest.mark.parametrize("mode", ["enable", "profiler"])
+@given(seed=st.integers(0, 10_000))
 @settings(max_examples=6, deadline=None)
-def test_mining_unchanged_by_tracing(seed):
+def test_mining_unchanged_by_tracing(mode, seed):
     """Disabled tracing adds zero device dispatches and changes no
-    frequent map; enabling it changes no results either."""
+    frequent map; enabling the buffer or running a profiler session
+    changes no results or dispatch counts either."""
     # hypothesis reuses one fixture across examples: reset per example
     trace.disable()
     trace.clear()
@@ -291,19 +390,22 @@ def test_mining_unchanged_by_tracing(seed):
     assert got_off.patterns == want.patterns
     assert m_off.n_device_calls == base.n_device_calls
 
-    trace.enable()
-    m_on = AcceleratedMiner(db)
-    got_on = m_on.mine_rs(MINSUP, max_len=MAX_LEN)
-    trace.disable()
+    with _traced(mode):
+        m_on = AcceleratedMiner(db)
+        got_on = m_on.mine_rs(MINSUP, max_len=MAX_LEN)
     assert got_on.patterns == want.patterns
     assert m_on.n_device_calls == base.n_device_calls
-    assert any(e["cat"] == "wall" for e in trace.tracer.events)
+    if mode == "enable":
+        assert any(e["cat"] == "wall" for e in trace.tracer.events)
+    else:  # a profiler session leaves the buffer off
+        assert trace.tracer.events == []
 
 
 @pytest.mark.slow
-@given(st.integers(0, 10_000))
+@pytest.mark.parametrize("mode", ["enable", "profiler"])
+@given(seed=st.integers(0, 10_000))
 @settings(max_examples=6, deadline=None)
-def test_serving_unchanged_by_tracing(seed):
+def test_serving_unchanged_by_tracing(mode, seed):
     # hypothesis reuses one fixture across examples: reset per example
     trace.disable()
     trace.clear()
@@ -319,22 +421,24 @@ def test_serving_unchanged_by_tracing(seed):
     want = srv.query(queries)
     assert trace.tracer.events == []
 
-    trace.enable()
-    srv_on = PatternServer(bank, bank_layout=layout)
-    got = srv_on.query(queries)
-    trace.disable()
+    with _traced(mode):
+        srv_on = PatternServer(bank, bank_layout=layout)
+        got = srv_on.query(queries)
     for r, w in zip(got, want):
         np.testing.assert_array_equal(r.contained, w.contained)
         assert r.topk == w.topk
     assert (srv_on.stats["device_batches"]
             == srv.stats["device_batches"])
-    assert trace.tracer.events  # enabled run did record spans
+    # the enabled run did record spans; a profiler session does not
+    # switch the buffer on
+    assert bool(trace.tracer.events) == (mode == "enable")
 
 
 @pytest.mark.slow
-@given(st.integers(0, 10_000))
+@pytest.mark.parametrize("mode", ["enable", "profiler"])
+@given(seed=st.integers(0, 10_000))
 @settings(max_examples=4, deadline=None)
-def test_streaming_unchanged_by_tracing(seed):
+def test_streaming_unchanged_by_tracing(mode, seed):
     # hypothesis reuses one fixture across examples: reset per example
     trace.disable()
     trace.clear()
@@ -353,16 +457,16 @@ def test_streaming_unchanged_by_tracing(seed):
 
     want = run()
     assert trace.tracer.events == []
-    trace.enable()
-    got = run()
-    trace.disable()
+    with _traced(mode):
+        got = run()
     assert got == want
 
 
 @pytest.mark.slow
-@given(st.integers(0, 10_000))
+@pytest.mark.parametrize("mode", ["enable", "profiler"])
+@given(seed=st.integers(0, 10_000))
 @settings(max_examples=4, deadline=None)
-def test_cluster_unchanged_by_tracing(seed):
+def test_cluster_unchanged_by_tracing(mode, seed):
     # hypothesis reuses one fixture across examples: reset per example
     trace.disable()
     trace.clear()
@@ -387,9 +491,8 @@ def test_cluster_unchanged_by_tracing(seed):
     want_rows, want_hits, want_batches = run()
     assert want_hits > 0  # the replay drain exercises the cache path
     assert trace.tracer.events == []
-    trace.enable()
-    got_rows, got_hits, got_batches = run()
-    trace.disable()
+    with _traced(mode):
+        got_rows, got_hits, got_batches = run()
     np.testing.assert_array_equal(got_rows, want_rows)
     assert (got_hits, got_batches) == (want_hits, want_batches)
 
@@ -472,6 +575,86 @@ def test_traced_cluster_query_coverage(tmp_path):
     assert "cluster.route" in names and "cluster.cache" in names
 
 
+# ======================================= the profiler sink, end to end
+PHASE_SPANS = (
+    "mining.pack", "mining.scan", "mining.aggregate", "mining.children",
+    "mining.canonical", "mining.parent", "mining.rebuild",
+    "serving.fingerprint", "serving.readback", "serving.escalate",
+    "serving.token_index", "serving.join",
+    "cluster.submit", "cluster.flush", "cluster.fence", "cluster.collect",
+)
+
+
+def _mine_and_serve():
+    """One miner job, then its bank served through the admission
+    pipeline (submit / collect, 4-query flushes): everything the
+    profiler's trace has to name, with the observables tracing must
+    not change."""
+    db = random_db(3, n_seq=10)
+    m = AcceleratedMiner(db)
+    res = m.mine_rs(MINSUP, max_len=MAX_LEN)
+    bank = compile_bank(res)
+    cl = ServingCluster(bank, 1, flush_batch=4)
+    tickets = [cl.submit({0: [q]}) for q in random_db(4, n_seq=8)]
+    rows = np.stack([cl.collect(t)[0][0].contained for t in tickets])
+    st = cl.stats()
+    return (res.patterns, m.n_device_calls, rows,
+            st["shards_device_batches"], st["flush_batch"])
+
+
+def test_profiler_trace_holds_every_phase_span(tmp_path):
+    """Mining and serving inside a profiler session: the host plane of
+    the ``.xplane.pb`` names every host phase, and the patterns, rows
+    and device calls are bit-identical to an untraced run."""
+    want = _mine_and_serve()
+    with _profiled(str(tmp_path)) as path:
+        got = _mine_and_serve()
+    assert got[0] == want[0]
+    np.testing.assert_array_equal(got[2], want[2])
+    assert got[1] == want[1] and got[3:] == want[3:]
+    names = _host_events(path)
+    missing = [n for n in PHASE_SPANS if n not in names]
+    assert not missing, missing
+    # one span per unit of work: a scan and an aggregate per device
+    # call, a children span per expanded item
+    assert names["mining.scan"] == names["mining.aggregate"] == want[1]
+    assert names["mining.children"] >= names["mining.canonical"] > 0
+    assert names["cluster.flush"] == want[4]
+    assert trace.tracer.events == []
+
+
+def test_full_tracing_never_blocks_on_the_device(monkeypatch):
+    """The buffer's full mode records launch spans (``cat="dispatch"``)
+    that end when the async call returns: no ``block_until_ready``
+    from the tracer, and no host-clock ``device`` records."""
+    blocks = []
+    real_block = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: blocks.append(1) or real_block(x))
+    db = random_db(3, n_seq=10)
+    bank = compile_bank(
+        AcceleratedMiner(db).mine_rs(MINSUP, max_len=MAX_LEN))
+    queries = random_db(4, n_seq=6)
+    want = [PatternServer(bank, bank_layout=lay).query(queries)
+            for lay in ("flat", "trie")]
+    trace.enable()
+    got = [PatternServer(bank, bank_layout=lay).query(queries)
+           for lay in ("flat", "trie")]
+    ServingCluster(bank, 2).query_multi(_spread(queries, 2))
+    trace.disable()
+    assert blocks == []
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(
+            np.stack([r.contained for r in g]),
+            np.stack([r.contained for r in w]))
+    evs = trace.tracer.events
+    assert all(e["cat"] != "device" for e in evs)
+    assert not any(e["name"].endswith(".device") for e in evs)
+    dispatch = {e["name"] for e in evs if e["cat"] == "dispatch"}
+    assert {"serving.token_index", "serving.prescreen", "serving.join",
+            "serving.trie_advance"} <= dispatch
+
+
 # ================================================== sampled tracing
 def _cluster_run(bank, queries, H=2):
     """One fresh-cluster double drain; returns (rows, relevant stats)
@@ -488,11 +671,11 @@ def _cluster_run(bank, queries, H=2):
             st["shard_batches"], batches)
 
 
-def test_sampling_changes_no_results_or_dispatches():
+def test_sampling_changes_no_results_or_dispatches(monkeypatch):
     """The always-on contract at every rate: head sampling at
     0 / 0.3 / 1.0 and tail-only keep must leave query results, cache
     counters and device-dispatch counts bit-identical to tracing
-    disabled (sampled roots never fence)."""
+    disabled (sampled roots never block on the device)."""
     db = random_db(5, n_seq=10)
     bank = compile_bank(
         AcceleratedMiner(db).mine_rs(MINSUP, max_len=MAX_LEN))
@@ -510,6 +693,10 @@ def test_sampling_changes_no_results_or_dispatches():
         ("head 100%", dict(rate=1.0)),
         ("tail-only", dict(rate=0.0, latency_threshold=0.0)),
     ]
+    blocks = []
+    real_block = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: blocks.append(1) or real_block(x))
     for label, kw in modes:
         reg = MetricsRegistry()
         trace.clear()
@@ -530,8 +717,8 @@ def test_sampling_changes_no_results_or_dispatches():
             assert all(e.get("args", {}).get("anomaly")
                        for e in trace.tracer.events), \
                 f"[{label}] rate-0 sampling kept a non-anomalous root"
-        # sampled mode must never flip the full-trace fence on
-        assert not trace.fencing()
+        # sampled mode never blocks on the device
+        assert blocks == [], f"[{label}] blocked on the device"
 
 
 def test_sampled_root_records_children_tail_root_does_not():
