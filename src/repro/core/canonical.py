@@ -10,6 +10,13 @@ over all bijective relabelings onto {0..n-1}.
 Patterns mined in practice are small (a handful of vertices), so an exact
 search over relabelings with early pruning is both simple and fast; an
 LRU cache collapses repeated canonicalizations.
+
+``iso_invariant`` is a much cheaper key that any two relabelings of one
+pattern share (one round of colour refinement): equal canonical forms
+imply equal invariants, not the converse.  The accelerated miner groups
+a pattern's extensions by it before any canonical form, and a group
+whose union of supporting sequences is below sigma is dropped whole
+(``mining.prescreen``; counters ``signatures`` and ``canon_skipped``).
 """
 from __future__ import annotations
 
@@ -17,7 +24,7 @@ import itertools
 from functools import lru_cache
 from typing import Dict, Tuple
 
-from .graphseq import Pattern, TR, pattern_vertices
+from .graphseq import NO_VERTEX, Pattern, TR, pattern_vertices
 
 Code = Tuple[Tuple[Tuple[int, int, int, int], ...], ...]
 
@@ -70,6 +77,41 @@ def _canonical(p: Pattern) -> Tuple[Code, Tuple[Tuple[int, int], ...]]:
         if best is None or code < best:
             best, best_m = code, m
     return best, tuple(sorted(best_m.items()))  # type: ignore[return-value]
+
+
+def iso_invariant(p: Pattern) -> Tuple:
+    """A key equal for any two patterns that differ only by a vertex
+    relabeling - a necessary condition for equal canonical forms, not a
+    sufficient one.
+
+    A vertex's colour is the sorted tuple of ``(itemset index, type,
+    label)`` over its incident TRs; a vertex TR's key is ``(type,
+    label, colour)``, an edge TR's ``(type, label, lo, hi)`` over its
+    endpoints' colours; the invariant is, per itemset in order, its
+    sorted TR keys.  Itemset order is part of a pattern's identity
+    (canonical forms minimise over vertex relabelings only)."""
+    inc: Dict[int, list] = {}
+    for i, itemset in enumerate(p):
+        for t, u1, u2, label in itemset:
+            c = (i, int(t), label)
+            inc.setdefault(u1, []).append(c)
+            if u2 != NO_VERTEX:  # an edge TR
+                inc.setdefault(u2, []).append(c)
+    colour = {v: tuple(sorted(cs)) for v, cs in inc.items()}
+    out = []
+    for itemset in p:
+        keys = []
+        for t, u1, u2, label in itemset:
+            # plain ints: an IntEnum hashes and compares in Python
+            t = int(t)
+            if u2 == NO_VERTEX:
+                keys.append((t, label, colour[u1]))
+            else:
+                a, b = colour[u1], colour[u2]
+                keys.append((t, label, a, b) if a <= b else (t, label, b, a))
+        keys.sort()
+        out.append(tuple(keys))
+    return tuple(out)
 
 
 def canonical_code(p: Pattern) -> Code:

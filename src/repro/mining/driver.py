@@ -39,13 +39,25 @@ Host phases (``repro.obs.trace`` spans, which reach the JAX profiler's
 trace whenever a session runs): ``mining.pack`` (stacks and embedding
 encoding, once per slice), ``mining.scan`` (one chunk's dispatch and
 wait), ``mining.aggregate`` (its signatures read back and merged),
-and per item ``mining.children`` around ``mining.canonical`` (the
-signature -> extension -> canonical-form grouping), ``mining.parent``
-(the reverse-search membership test, once per frequent candidate) and
-``mining.rebuild`` (one kept child's embeddings).  The counters
-``candidates``, ``infrequent`` and ``rs_rejected`` count the funnel
-those phases walk: distinct canonical children, those below sigma,
-and those whose parent is another pattern.
+and per item ``mining.children`` around ``mining.prescreen`` (the
+signature -> extension -> isomorphism-invariant grouping),
+``mining.canonical`` (the canonical-form grouping of the surviving
+signatures), ``mining.parent`` (the reverse-search membership test,
+once per frequent candidate) and ``mining.rebuild`` (one kept child's
+embeddings).
+
+The prescreen is a support bound: every signature of one canonical
+child lies in one invariant group (``core.canonical.iso_invariant``),
+and the child's support is the union over a subset of that group, so
+a group whose union of supporting sequences is below sigma holds only
+infrequent children and no canonical form is computed for it.  The
+counters ``signatures`` (past the vertex-capacity guard) and
+``canon_skipped`` (those whose canonical form was skipped) say how
+often it engages.  ``candidates``, ``infrequent`` and ``rs_rejected``
+count the funnel those phases walk: candidates (distinct canonical
+children, plus one per pruned invariant group), those below sigma
+(pruned groups included), and those whose parent is another pattern;
+``candidates == infrequent + rs_rejected + n_enumerated``.
 """
 from __future__ import annotations
 
@@ -57,7 +69,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.canonical import canonical_form, canonical_map
+from ..core.canonical import canonical_form, canonical_map, iso_invariant
 from ..obs import trace
 from ..obs.metrics import MetricsRegistry
 from ..core.enumerate_host import Emb, apply_extension
@@ -135,14 +147,21 @@ class AcceleratedMiner:
             f"{metrics_ns}.n_device_calls")
         self._h_wave = self.metrics.histogram(
             f"{metrics_ns}.wave_patterns")
-        # the reverse-search funnel: distinct canonical children, those
-        # below sigma, and those that failed ``parent(child) == pattern``
+        # the reverse-search funnel: candidates (distinct canonical
+        # children, one per pruned invariant group), those below sigma,
+        # and those that failed ``parent(child) == pattern``
         self._c_candidates = self.metrics.counter(
             f"{metrics_ns}.candidates")
         self._c_infrequent = self.metrics.counter(
             f"{metrics_ns}.infrequent")
         self._c_rs_rejected = self.metrics.counter(
             f"{metrics_ns}.rs_rejected")
+        # the prescreen: signatures past the capacity guard, and those
+        # whose canonical form the support bound skipped
+        self._c_signatures = self.metrics.counter(
+            f"{metrics_ns}.signatures")
+        self._c_canon_skipped = self.metrics.counter(
+            f"{metrics_ns}.canon_skipped")
         # always-on latency percentiles: wall (launch + blocked) per
         # packed device chunk, log-scale buckets
         self._h_wave_s = self.metrics.bucket_histogram(
@@ -352,21 +371,43 @@ class AcceleratedMiner:
         rs: bool,
         want_embs: Optional[Callable[[Pattern], bool]],
     ) -> List[Tuple[Pattern, Set[int], List[Emb]]]:
-        by_child: Dict[Pattern, Tuple[Set[int], int, List[np.ndarray]]] = {}
-        with trace.span("mining.canonical", signatures=len(merged)):
+        # support-bound prescreen: group by the isomorphism invariant;
+        # a group whose union of gids is below sigma holds only
+        # infrequent children, so none of it is canonicalized.  Each raw
+        # entry: (sig, child_raw, its group's gid union, gset, et_rows)
+        raw: List[Tuple[int, Pattern, Set[int], Set[int],
+                        List[np.ndarray]]] = []
+        groups: Dict[tuple, Set[int]] = {}
+        with trace.span("mining.prescreen", signatures=len(merged)):
             for sig, (gset, et_rows) in merged.items():
                 key = signature_to_extkey(sig)
                 if max(key[1].u1, key[1].u2) >= self.nv:
                     continue  # vertex-capacity guard
                 child_raw = apply_extension(pattern, key)
+                inv = iso_invariant(child_raw)
+                group = groups.get(inv)
+                if group is None:
+                    group = groups[inv] = set(gset)
+                else:
+                    group.update(gset)
+                raw.append((sig, child_raw, group, gset, et_rows))
+        pruned = sum(len(g) < min_support for g in groups.values())
+        by_child: Dict[Pattern, Tuple[Set[int], int, Pattern,
+                                      List[np.ndarray]]] = {}
+        skipped = 0
+        with trace.span("mining.canonical", signatures=len(raw)):
+            for sig, child_raw, group, gset, et_rows in raw:
+                if len(group) < min_support:
+                    skipped += 1
+                    continue
                 child = canonical_form(child_raw)
                 if child in by_child:
                     by_child[child][0].update(gset)
                 else:
-                    by_child[child] = (set(gset), sig, et_rows)
+                    by_child[child] = (set(gset), sig, child_raw, et_rows)
         out: List[Tuple[Pattern, Set[int], List[Emb]]] = []
         infrequent = rejected = 0
-        for child, (gids, sig, et_rows) in by_child.items():
+        for child, (gids, sig, child_raw, et_rows) in by_child.items():
             if len(gids) < min_support:
                 infrequent += 1
                 continue
@@ -381,14 +422,14 @@ class AcceleratedMiner:
                 out.append((child, gids, []))
                 continue
             with trace.span("mining.rebuild"):
-                key = signature_to_extkey(sig)
-                child_raw = apply_extension(pattern, key)
                 child_embs = self._rebuild_embeddings(
                     pattern, enc, sig, et_rows, child_raw
                 )
             out.append((child, gids, child_embs))
-        self._c_candidates.inc(len(by_child))
-        self._c_infrequent.inc(infrequent)
+        self._c_signatures.inc(len(raw))
+        self._c_canon_skipped.inc(skipped)
+        self._c_candidates.inc(len(by_child) + pruned)
+        self._c_infrequent.inc(infrequent + pruned)
         self._c_rs_rejected.inc(rejected)
         return out
 

@@ -13,6 +13,7 @@ from repro.core.canonical import (
     canonical_form,
     canonical_map,
     is_canonical,
+    iso_invariant,
     relabel_pattern,
 )
 from repro.core.compile import compile_sequence, diff_graphs, reconstruct
@@ -131,6 +132,42 @@ def test_canonical_invariant_under_relabeling(seed):
         rng.shuffle(perm)
         relabeled = relabel_pattern(pat, {v: 100 + perm[i] for i, v in enumerate(vs)})
         assert canonical_form(pat) == canonical_form(relabeled)
+
+
+@settings(max_examples=50, deadline=None)
+@given(st.integers(0, 10_000))
+def test_iso_invariant_under_relabeling(seed):
+    """Relabelings share the invariant, as they share canonical forms."""
+    rng = random.Random(seed)
+    db = random_db(seed, n_seq=2)
+    for s in db:
+        pat = pattern_from_lists([it for it in s if it])
+        if not pat:
+            continue
+        vs = pattern_vertices(pat)
+        perm = list(range(len(vs)))
+        rng.shuffle(perm)
+        relabeled = relabel_pattern(pat, {v: 100 + perm[i] for i, v in enumerate(vs)})
+        assert iso_invariant(pat) == iso_invariant(relabeled)
+        assert iso_invariant(pat) == iso_invariant(canonical_form(pat))
+
+
+def test_iso_invariant_is_only_necessary():
+    """A 6-cycle and two triangles of same-label edge inserts in one
+    itemset: every vertex has two like edges, so the invariant cannot
+    tell them apart, while their canonical forms differ."""
+    def ring(cycles):
+        return pattern_from_lists([[
+            edge_tr(TRType.EI, c[i], c[(i + 1) % len(c)], 1)
+            for c in cycles for i in range(len(c))
+        ]])
+
+    hexagon = ring([(0, 1, 2, 3, 4, 5)])
+    triangles = ring([(0, 1, 2), (3, 4, 5)])
+    assert iso_invariant(hexagon) == iso_invariant(triangles)
+    assert canonical_form(hexagon) != canonical_form(triangles)
+    # a ring of another size has another invariant
+    assert iso_invariant(hexagon) != iso_invariant(ring([(0, 1, 2, 3, 4)]))
 
 
 def test_canonical_idempotent_and_compact():

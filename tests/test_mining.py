@@ -11,6 +11,9 @@ except ImportError:  # optional dep: seeded-sampling fallback
 import jax.numpy as jnp
 
 from conftest import random_db
+from repro.core.canonical import canonical_form, iso_invariant
+from repro.core.enumerate_host import apply_extension
+from repro.core.graphseq import TRType, edge_tr, pattern_from_lists
 from repro.core.gtrace import mine_gtrace
 from repro.core.reverse_search import mine_gtrace_rs
 from repro.mining.driver import AcceleratedMiner
@@ -247,3 +250,59 @@ def test_reverse_search_funnel_counters():
                     + res.n_enumerated)
     assert snap["mining.rs_rejected"] > 0
     assert snap["mining.infrequent"] > 0
+    # the support-bound prescreen engaged: some signatures skipped
+    # their canonical form, never more than passed the capacity guard
+    assert 0 < snap["mining.canon_skipped"] <= snap["mining.signatures"]
+
+
+def _ei_sig(u1, u2, label=1):
+    """Signature of an edge insert into the pattern's first itemset."""
+    return pack_signature(0, 0, int(TRType.EI), u1, u2, label)
+
+
+@pytest.mark.parametrize("sigma", [2, 3])
+def test_prescreen_keeps_children_that_share_an_invariant(sigma):
+    """Two paths of four vertices: closing one into a 4-cycle and
+    joining the two into a path of eight give non-isomorphic children
+    with one invariant (each has five edges between degree-2 vertices
+    and two from a degree-1 vertex).  Their group's union reaches sigma
+    3 while neither child does, so both must come out infrequent; at
+    sigma 2 both are frequent and kept apart.  A third extension, in a
+    group of its own below sigma, is pruned before canonicalization."""
+    pattern = pattern_from_lists([[
+        edge_tr(TRType.EI, a, a + 1, 1) for a in (0, 1, 2, 4, 5, 6)]])
+    cycle, path, pruned = _ei_sig(0, 3), _ei_sig(3, 4), _ei_sig(3, 8)
+    gids = {cycle: {0, 1}, path: {2, 3}, pruned: {4}}
+    merged = {sig: (set(g), []) for sig, g in gids.items()}
+    m = AcceleratedMiner(random_db(0, n_seq=5))
+    out = m._children_from_merged(pattern, None, merged, sigma, False,
+                                  lambda child: False)
+    kids = {sig: canonical_form(apply_extension(
+        pattern, signature_to_extkey(sig))) for sig in gids}
+    assert kids[cycle] != kids[path]
+    assert len({iso_invariant(c) for c in kids.values()}) == 2
+    want = {kids[s]: gids[s] for s in (cycle, path) if len(gids[s]) >= sigma}
+    assert {c: g for c, g, _ in out} == want
+    assert all(embs == [] for _, _, embs in out)
+    assert {s: g for s, (g, _) in merged.items()} == gids  # not mutated
+    snap = m.metrics.snapshot("mining")
+    assert snap["mining.signatures"] == 3
+    assert snap["mining.canon_skipped"] == 1
+    assert snap["mining.candidates"] == 3
+    assert snap["mining.infrequent"] == 3 - len(want)
+
+
+@pytest.mark.parametrize("seed", [11, 12, 13])
+@pytest.mark.parametrize("rs", [True, False])
+def test_prescreen_mines_equal_to_core(seed, rs):
+    """At a sigma where the prescreen prunes some invariant groups and
+    keeps others, both accelerated miners equal the host reference."""
+    db = random_db(seed, n_seq=10, n_steps=4, n_v=4)
+    m = AcceleratedMiner(db)
+    if rs:
+        core, dev = mine_gtrace_rs(db, 3, max_len=4), m.mine_rs(3, max_len=4)
+    else:
+        core, dev = mine_gtrace(db, 3, max_len=4), m.mine_gtrace(3, max_len=4)
+    assert core.patterns == dev.patterns
+    snap = m.metrics.snapshot("mining")
+    assert 0 < snap["mining.canon_skipped"] < snap["mining.signatures"]

@@ -578,7 +578,8 @@ def test_traced_cluster_query_coverage(tmp_path):
 # ======================================= the profiler sink, end to end
 PHASE_SPANS = (
     "mining.pack", "mining.scan", "mining.aggregate", "mining.children",
-    "mining.canonical", "mining.parent", "mining.rebuild",
+    "mining.prescreen", "mining.canonical", "mining.parent",
+    "mining.rebuild",
     "serving.fingerprint", "serving.readback", "serving.escalate",
     "serving.token_index", "serving.join",
     "cluster.submit", "cluster.flush", "cluster.fence", "cluster.collect",
@@ -619,6 +620,7 @@ def test_profiler_trace_holds_every_phase_span(tmp_path):
     # call, a children span per expanded item
     assert names["mining.scan"] == names["mining.aggregate"] == want[1]
     assert names["mining.children"] >= names["mining.canonical"] > 0
+    assert names["mining.prescreen"] == names["mining.canonical"]
     assert names["cluster.flush"] == want[4]
     assert trace.tracer.events == []
 
