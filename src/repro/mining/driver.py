@@ -17,17 +17,28 @@ per-row ``pattern_id`` axis (stacked ``existing`` tables and per-row
 ``engine.match_signatures_batch``), and ONE dispatch covers the whole
 chunk instead of one per pattern.  Signatures come back namespaced by
 ``pattern_id`` (``engine.aggregate_host_batch``), so the host finalize
-splits per pattern exactly as before; child embeddings are rebuilt with
-numpy scatter/stack ops over the whole (e,t) row set rather than a
-Python loop per row.  ``dispatch="pattern"`` keeps the seed's
-one-pattern-at-a-time traversal (same code path, slices of size one) as
-the benchmark baseline; both dispatch modes return bit-equal
-``MiningResult``s.
+splits per pattern exactly as before.  ``dispatch="pattern"`` keeps
+the seed's one-pattern-at-a-time traversal (same code path, slices of
+size one) as the benchmark baseline; both dispatch modes return
+bit-equal ``MiningResult``s.
+
+The frontier is encoded end to end: a pending item's embeddings are the
+``Enc`` triple (``gid`` [E], ``phi`` [E, ni] padded with ``PAD_PHI``,
+``psi`` [E, nv] padded with ``PAD_PSI``) that the next scan packs as it
+is.  A kept child's rows are rebuilt from its parent's ``Enc`` with
+numpy scatter/stack ops over the whole (e,t) row set, deduped, and
+padded straight into a new ``Enc``; no Python runs per row between a
+chunk's readback and the next dispatch.  ``Emb`` tuples appear only at
+``expand_children`` (the single-item public helper, which decodes its
+result) and at checkpoints; a ``List[Emb]`` handed in is encoded when
+its slice is packed.
 
 A wavefront is just a reordered work stack, so the miner stays
 checkpointable (see checkpoint.py): the pending slice items plus the
 accumulated next wave serialize exactly like the seed stack, and a
-resume re-enqueues them - supports are per-subtree and idempotent.
+resume re-enqueues them - supports are per-subtree and idempotent.  A
+checkpoint decodes the pending items to ``Emb`` lists (the wire format
+is unchanged) and a resume encodes them again.
 
 Device timing: jax dispatch is async, so the launch and the execution
 are timed separately - ``dispatch_seconds`` stops when the call
@@ -36,15 +47,18 @@ returns (launch cost only), ``device_seconds`` after
 signatures right after in any case).
 
 Host phases (``repro.obs.trace`` spans, which reach the JAX profiler's
-trace whenever a session runs): ``mining.pack`` (stacks and embedding
-encoding, once per slice), ``mining.scan`` (one chunk's dispatch and
-wait), ``mining.aggregate`` (its signatures read back and merged),
-and per item ``mining.children`` around ``mining.prescreen`` (the
-signature -> extension -> isomorphism-invariant grouping),
+trace whenever a session runs): ``mining.pack`` (the pattern stacks
+and the items' row arrays concatenated, once per slice),
+``mining.scan`` (one chunk's dispatch and wait), ``mining.aggregate``
+(its signatures read back and merged), and per item
+``mining.children`` around ``mining.prescreen`` (the signature ->
+extension -> isomorphism-invariant grouping),
 ``mining.canonical`` (the canonical-form grouping of the surviving
 signatures), ``mining.parent`` (the reverse-search membership test,
 once per frequent candidate) and ``mining.rebuild`` (one kept child's
-embeddings).
+embeddings; ``rows=`` the (e,t) rows it reads).  The counter
+``rebuild_rows`` counts the rows the rebuilds emit, the frontier the
+job carries.
 
 The prescreen is a support bound: every signature of one canonical
 child lies in one invariant group (``core.canonical.iso_invariant``),
@@ -64,7 +78,9 @@ from __future__ import annotations
 import math
 import time
 from collections import deque
-from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
+from typing import (
+    Callable, Dict, List, Optional, Sequence, Set, Tuple, Union,
+)
 
 import jax.numpy as jnp
 import numpy as np
@@ -79,7 +95,9 @@ from ..core.reverse_search import parent
 from .encoding import (
     PAD_PHI,
     PAD_PSI,
+    Enc,
     TokenDB,
+    decode_embeddings,
     encode_db,
     encode_embeddings,
     encode_pattern_trs,
@@ -96,8 +114,8 @@ from .engine import (
 
 MAX_PATTERN_TRS = 64
 
-# encoded row arrays of one pattern's embedding list: (gid, phi, psi)
-Enc = Tuple[np.ndarray, np.ndarray, np.ndarray]
+# a frontier item: a pattern and its embedding rows, encoded
+Item = Tuple[Pattern, Enc]
 
 
 def _pow2_pad(n: int, cap: Optional[int] = None) -> int:
@@ -135,6 +153,12 @@ class AcceleratedMiner:
         self.wave_rows = 4 * e_batch if wave_rows is None else wave_rows
         self.tdb: TokenDB = encode_db(db)
         self.tokens = jnp.asarray(self.tdb.tokens)
+        # the rows of a child whose embeddings were not rebuilt
+        self._no_rows: Enc = (
+            np.zeros((0,), np.int32),
+            np.zeros((0, self.ni), np.int32),
+            np.zeros((0, self.nv), np.int32),
+        )
         # counters live in a registry (private by default; pass
         # ``metrics=`` to accumulate across miner rebuilds, e.g. the
         # streaming bank's incremental refreshes)
@@ -162,6 +186,9 @@ class AcceleratedMiner:
             f"{metrics_ns}.signatures")
         self._c_canon_skipped = self.metrics.counter(
             f"{metrics_ns}.canon_skipped")
+        # embedding rows the rebuilds emit (the frontier carried)
+        self._c_rebuild_rows = self.metrics.counter(
+            f"{metrics_ns}.rebuild_rows")
         # always-on latency percentiles: wall (launch + blocked) per
         # packed device chunk, log-scale buckets
         self._h_wave_s = self.metrics.bucket_histogram(
@@ -194,16 +221,34 @@ class AcceleratedMiner:
         return MODE_EDGE_PHASE
 
     # ------------------------------------------------------------- scans
+    def _encoded(self, embs: Union[Enc, List[Emb]]) -> Enc:
+        """An item's rows as ``Enc``: the frontier's own arrays as they
+        are, a ``List[Emb]`` (an outside caller's) encoded."""
+        if (isinstance(embs, tuple) and len(embs) == 3
+                and isinstance(embs[0], np.ndarray)):
+            return embs
+        return encode_embeddings(embs, self.ni, self.nv)
+
+    def _root_item(self) -> Item:
+        """The empty pattern, embedded once in every sequence."""
+        G = len(self.db)
+        return ((), (
+            np.arange(G, dtype=np.int32),
+            np.full((G, self.ni), PAD_PHI, np.int32),
+            np.full((G, self.nv), PAD_PSI, np.int32),
+        ))
+
     def _scan_batch(
-        self, items: List[Tuple[Pattern, List[Emb]]], modes: List[int]
+        self, items: List[Tuple[Pattern, Union[Enc, List[Emb]]]],
+        modes: List[int],
     ) -> Tuple[List[Dict[int, Tuple[Set[int], List[np.ndarray]]]],
                List[Enc]]:
         """Run the device scans for a wavefront slice: all items' rows
         are packed into shared pow-2 chunks (a chunk freely spans
         pattern boundaries) and each chunk is ONE device dispatch.
         Returns, per item, ``{sig: (gid_set, (e,t) rows)}`` with ``e``
-        local to the item's embedding list, plus the item's encoded row
-        arrays for the vectorized embedding rebuild."""
+        local to the item's embedding rows, plus the item's ``Enc`` for
+        the vectorized embedding rebuild."""
         n = len(items)
         merged: List[Dict[int, Tuple[Set[int], List[np.ndarray]]]] = [
             {} for _ in items
@@ -224,11 +269,8 @@ class AcceleratedMiner:
             npat_j = jnp.asarray(npat_stack)
             mode_j = jnp.asarray(mode_stack)
 
-            enc: List[Enc] = [
-                encode_embeddings(embs, self.ni, self.nv)
-                for _, embs in items
-            ]
-            lens = np.asarray([len(embs) for _, embs in items], np.int64)
+            enc = [self._encoded(embs) for _, embs in items]
+            lens = np.asarray([len(e[0]) for e in enc], np.int64)
             offs = np.cumsum(lens) - lens
             R = int(lens.sum())
             if R == 0:
@@ -295,13 +337,15 @@ class AcceleratedMiner:
         sig: int,
         et_rows: List[np.ndarray],
         child_raw: Pattern,
-    ) -> List[Emb]:
-        """Vectorized child-embedding rebuild: phi insertion, the psi
-        variant construction, canonical remap, and first-seen dedup are
-        numpy column ops over the whole (e,t) row set (the extension key
-        - and therefore the variant case - is constant per signature, so
-        the only per-row Python left is materializing the final Emb
-        tuples from the deduped rows)."""
+    ) -> Enc:
+        """Vectorized child-embedding rebuild, from the parent's ``Enc``
+        to the child's: phi insertion, the psi variant construction,
+        canonical remap, first-seen dedup and the padding to ``ni`` /
+        ``nv`` columns are numpy column ops over the whole (e,t) row set
+        (the extension key - and therefore the variant case - is
+        constant per signature).  No Python runs per row; the result is
+        what ``encode_embeddings`` makes of the deduped rows as ``Emb``
+        tuples, bit for bit and in the same order."""
         (slot_kind, slot_idx), ptr = signature_to_extkey(sig)
         nv = len(pattern_vertices(pattern))
         n_pat = len(pattern)
@@ -352,14 +396,12 @@ class AcceleratedMiner:
             rows = variants[0]
         _, first = np.unique(rows, axis=0, return_index=True)
         rows = rows[np.sort(first)]  # dedup, first-seen order
-        return [
-            (
-                int(r[0]),
-                tuple(int(x) for x in r[1:1 + n_phi]),
-                tuple(enumerate(int(x) for x in r[1 + n_phi:])),
-            )
-            for r in rows
-        ]
+        R = len(rows)
+        phi = np.full((R, self.ni), PAD_PHI, np.int32)
+        phi[:, :n_phi] = rows[:, 1:1 + n_phi]
+        psi = np.full((R, self.nv), PAD_PSI, np.int32)
+        psi[:, :nv_child] = rows[:, 1 + n_phi:]
+        return rows[:, 0].astype(np.int32), phi, psi
 
     # -------------------------------------------------- child expansion
     def _children_from_merged(
@@ -370,7 +412,7 @@ class AcceleratedMiner:
         min_support: int,
         rs: bool,
         want_embs: Optional[Callable[[Pattern], bool]],
-    ) -> List[Tuple[Pattern, Set[int], List[Emb]]]:
+    ) -> List[Tuple[Pattern, Set[int], Enc]]:
         # support-bound prescreen: group by the isomorphism invariant;
         # a group whose union of gids is below sigma holds only
         # infrequent children, so none of it is canonicalized.  Each raw
@@ -405,8 +447,8 @@ class AcceleratedMiner:
                     by_child[child][0].update(gset)
                 else:
                     by_child[child] = (set(gset), sig, child_raw, et_rows)
-        out: List[Tuple[Pattern, Set[int], List[Emb]]] = []
-        infrequent = rejected = 0
+        out: List[Tuple[Pattern, Set[int], Enc]] = []
+        infrequent = rejected = rebuilt_rows = 0
         for child, (gids, sig, child_raw, et_rows) in by_child.items():
             if len(gids) < min_support:
                 infrequent += 1
@@ -419,35 +461,42 @@ class AcceleratedMiner:
                     rejected += 1
                     continue
             if want_embs is not None and not want_embs(child):
-                out.append((child, gids, []))
+                out.append((child, gids, self._no_rows))
                 continue
-            with trace.span("mining.rebuild"):
-                child_embs = self._rebuild_embeddings(
+            with trace.span("mining.rebuild",
+                            rows=sum(map(len, et_rows))):
+                child_enc = self._rebuild_embeddings(
                     pattern, enc, sig, et_rows, child_raw
                 )
-            out.append((child, gids, child_embs))
+            rebuilt_rows += len(child_enc[0])
+            out.append((child, gids, child_enc))
         self._c_signatures.inc(len(raw))
         self._c_canon_skipped.inc(skipped)
         self._c_candidates.inc(len(by_child) + pruned)
         self._c_infrequent.inc(infrequent + pruned)
         self._c_rs_rejected.inc(rejected)
+        self._c_rebuild_rows.inc(rebuilt_rows)
         return out
 
     def expand_children_batch(
         self,
-        items: Sequence[Tuple[Pattern, List[Emb]]],
+        items: Sequence[Tuple[Pattern, Union[Enc, List[Emb]]]],
         min_support: int,
         *,
         rs: bool = True,
         want_embs: Optional[Callable[[Pattern], bool]] = None,
-    ) -> List[List[Tuple[Pattern, Set[int], List[Emb]]]]:
+    ) -> List[List[Tuple[Pattern, Set[int], Enc]]]:
         """One batched expansion of a whole wavefront slice: every
         item's DB scan shares the packed device chunks (see
         ``_scan_batch``); the result is per-item, aligned with
-        ``items``, each entry exactly what ``expand_children`` would
-        have returned for that item alone.  Items at the itemset
-        capacity come back empty (same guard as the single-item path)."""
-        out: List[List[Tuple[Pattern, Set[int], List[Emb]]]] = [
+        ``items``, each entry what ``expand_children`` would have
+        returned for that item alone but with each child's embeddings
+        as an ``Enc`` (``decode_embeddings`` gives the ``Emb`` list; a
+        child whose rebuild ``want_embs`` skipped has zero rows).  An
+        item's embeddings may come as an ``Enc`` (the frontier's own)
+        or as a ``List[Emb]``.  Items at the itemset capacity come back
+        empty (same guard as the single-item path)."""
+        out: List[List[Tuple[Pattern, Set[int], Enc]]] = [
             [] for _ in items
         ]
         live = [
@@ -492,35 +541,38 @@ class AcceleratedMiner:
         descend into (the clean-subtree prune); such children come back
         with ``[]``.  Respects the miner's itemset/vertex capacity
         guards."""
-        return self.expand_children_batch(
-            [(pattern, embs)], min_support, rs=rs, want_embs=want_embs
-        )[0]
+        return [
+            (child, gids, decode_embeddings(enc))
+            for child, gids, enc in self.expand_children_batch(
+                [(pattern, embs)], min_support, rs=rs, want_embs=want_embs
+            )[0]
+        ]
 
     # ------------------------------------------------------------ mining
     def _take_slice(
         self,
-        pending: "deque[Tuple[Pattern, List[Emb]]]",
+        pending: "deque[Item]",
         max_len: Optional[int],
         wavefront: bool,
-    ) -> List[Tuple[Pattern, List[Emb]]]:
+    ) -> List[Item]:
         """Pop the next expansion slice off the work pool, applying the
         length/capacity guards exactly as the seed stack loop did.
         Wavefront mode drains FIFO up to the slice bounds (many
         patterns, one batched call); pattern mode pops LIFO one at a
         time (the seed's per-pattern dispatch, kept as the benchmark
         baseline)."""
-        items: List[Tuple[Pattern, List[Emb]]] = []
+        items: List[Item] = []
         rows = 0
         while pending:
-            pattern, embs = (
+            pattern, enc = (
                 pending.popleft() if wavefront else pending.pop()
             )
             if max_len is not None and pattern_length(pattern) >= max_len:
                 continue
             if len(pattern) >= self.ni:
                 continue  # capacity guard (configurable)
-            items.append((pattern, embs))
-            rows += len(embs)
+            items.append((pattern, enc))
+            rows += len(enc[0])
             if (
                 not wavefront
                 or len(items) >= self.wave_patterns
@@ -541,15 +593,15 @@ class AcceleratedMiner:
         from .checkpoint import load_state, save_state
 
         res = MiningResult()
-        root: Tuple[Pattern, List[Emb]] = (
-            (), [(g, (), ()) for g in range(len(self.db))]
-        )
-        pending: "deque[Tuple[Pattern, List[Emb]]]" = deque([root])
+        pending: "deque[Item]" = deque([self._root_item()])
         if resume and checkpoint_path:
             patterns, stack, meta = load_state(checkpoint_path)
             res.patterns.update(patterns)
             res.n_enumerated = meta.get("n_enumerated", len(patterns))
-            pending = deque(stack)
+            pending = deque(
+                (p, encode_embeddings(embs, self.ni, self.nv))
+                for p, embs in stack
+            )
         # canonical dedup is baseline-only (rs children are unique by
         # the membership test); skip their embedding rebuilds too
         want = (
@@ -570,12 +622,12 @@ class AcceleratedMiner:
                     for kids in self.expand_children_batch(
                         items, min_support, rs=rs, want_embs=want
                     ):
-                        for child, gids, child_embs in kids:
+                        for child, gids, child_enc in kids:
                             if not rs and child in res.patterns:
                                 continue
                             res.patterns[child] = len(gids)
                             res.n_enumerated += 1
-                            pending.append((child, child_embs))
+                            pending.append((child, child_enc))
                 expansions_since_ckpt += len(items)
                 if (
                     checkpoint_path
@@ -584,7 +636,8 @@ class AcceleratedMiner:
                     with trace.span("mining.checkpoint"):
                         save_state(
                             checkpoint_path, res.patterns,
-                            list(pending),
+                            [(p, decode_embeddings(enc))
+                             for p, enc in pending],
                             meta={"min_support": min_support, "rs": rs,
                                   "n_enumerated": res.n_enumerated},
                         )

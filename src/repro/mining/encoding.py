@@ -7,6 +7,12 @@ The device engine operates on fixed-shape int32 tensors:
 * embeddings of the current pattern: ``gid`` [E], ``phi`` [E, NI]
   (data itemset index per pattern itemset, ``PAD_PHI`` beyond n),
   ``psi`` [E, NV] (data vertex per pattern vertex, ``PAD_PSI`` beyond m).
+  This triple (``Enc``) is the miner's frontier representation from one
+  scan to the next.  ``encode_embeddings`` builds it from a list of
+  ``Emb`` tuples and ``decode_embeddings`` is its exact inverse (phi up
+  to the first ``PAD_PHI``, psi pairs up to the first ``PAD_PSI``); the
+  tuples appear only at the miner's public single-item expansion and in
+  checkpoints.
 
 Extension *signatures* pack a candidate one-TR extension in pattern
 coordinates into one int64 so that discovery + support counting reduce to
@@ -93,9 +99,11 @@ def encode_db(db: Sequence[TRSeq], pad_to: int | None = None,
                    n_labels=max_label + 1)
 
 
-def encode_embeddings(
-    embs: Sequence[Emb], ni: int, nv: int
-) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+# encoded rows of one pattern's embedding list: (gid, phi, psi)
+Enc = Tuple[np.ndarray, np.ndarray, np.ndarray]
+
+
+def encode_embeddings(embs: Sequence[Emb], ni: int, nv: int) -> Enc:
     E = len(embs)
     gid = np.zeros((E,), dtype=np.int32)
     phi = np.full((E, ni), PAD_PHI, dtype=np.int32)
@@ -107,6 +115,25 @@ def encode_embeddings(
         for pv, dv in ps:
             psi[i, pv] = dv
     return gid, phi, psi
+
+
+def _prefix_len(a: np.ndarray, pad: np.int32) -> np.ndarray:
+    """Per row, the number of columns before the first ``pad``."""
+    is_pad = a == pad
+    return np.where(is_pad.any(axis=1), is_pad.argmax(axis=1), a.shape[1])
+
+
+def decode_embeddings(enc: Enc) -> List[Emb]:
+    """The exact inverse of ``encode_embeddings``."""
+    gid, phi, psi = enc
+    return [
+        (g, tuple(ph[:n_ph]), tuple(enumerate(ps[:n_ps])))
+        for g, ph, ps, n_ph, n_ps in zip(
+            gid.tolist(), phi.tolist(), psi.tolist(),
+            _prefix_len(phi, PAD_PHI).tolist(),
+            _prefix_len(psi, PAD_PSI).tolist(),
+        )
+    ]
 
 
 def encode_pattern_trs(p: Pattern, max_rows: int) -> np.ndarray:

@@ -193,15 +193,15 @@ def refresh_frontier(
 
     def want_embs(child: Pattern) -> bool:
         # clean children are retained, never descended - skip the
-        # embedding rebuild (the expensive host part of a scan)
+        # embedding rebuild (their Enc comes back with zero rows)
         return not is_clean(child)
 
     # same wavefront scheduling as AcceleratedMiner._mine: the dirty
     # frontier is drained in slices and every slice's scans share
     # packed device chunks, so streaming refresh() and the sharded
     # reconcile get the cross-pattern batching for free
-    root: Pattern = ()
-    pending = deque([(root, [(g, (), ()) for g in range(len(db))])])
+    root, root_enc = miner._root_item()
+    pending = deque([(root, root_enc)])
     while pending:
         items = miner._take_slice(pending, max_len, wavefront=True)
         if not items:
@@ -210,7 +210,7 @@ def refresh_frontier(
         for (pattern, _), kids in zip(items, miner.expand_children_batch(
             items, min_support, rs=True, want_embs=want_embs
         )):
-            for child, gids, child_embs in kids:
+            for child, gids, child_enc in kids:
                 res.patterns[child] = len(gids)
                 if pattern == root:
                     if is_clean(child):
@@ -229,5 +229,5 @@ def refresh_frontier(
                 else:
                     res.gids[child] = gids
                     res.discovered += 1
-                    pending.append((child, child_embs))
+                    pending.append((child, child_enc))
     return res

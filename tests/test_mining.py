@@ -11,13 +11,18 @@ except ImportError:  # optional dep: seeded-sampling fallback
 import jax.numpy as jnp
 
 from conftest import random_db
-from repro.core.canonical import canonical_form, iso_invariant
+from repro.core.canonical import canonical_form, canonical_map, iso_invariant
 from repro.core.enumerate_host import apply_extension
-from repro.core.graphseq import TRType, edge_tr, pattern_from_lists
+from repro.core.graphseq import (
+    TRType, edge_tr, pattern_from_lists, pattern_vertices,
+)
 from repro.core.gtrace import mine_gtrace
 from repro.core.reverse_search import mine_gtrace_rs
 from repro.mining.driver import AcceleratedMiner
 from repro.mining.encoding import (
+    PAD_PHI,
+    PAD_PSI,
+    decode_embeddings,
     encode_db,
     encode_embeddings,
     encode_pattern_trs,
@@ -130,8 +135,9 @@ def test_expand_children_batch_matches_single():
     for (pattern, embs), got in zip(items, batched):
         want = AcceleratedMiner(db).expand_children(pattern, embs, 2)
         # chunk packing may reorder signature discovery, so compare
-        # children order-insensitively; embedding lists as sets
-        assert {c: (g, set(e)) for c, g, e in got} == \
+        # children order-insensitively; the batch's encoded embeddings
+        # decoded, and embedding lists as sets
+        assert {c: (g, set(decode_embeddings(e))) for c, g, e in got} == \
             {c: (g, set(e)) for c, g, e in want}
 
 
@@ -283,7 +289,8 @@ def test_prescreen_keeps_children_that_share_an_invariant(sigma):
     assert len({iso_invariant(c) for c in kids.values()}) == 2
     want = {kids[s]: gids[s] for s in (cycle, path) if len(gids[s]) >= sigma}
     assert {c: g for c, g, _ in out} == want
-    assert all(embs == [] for _, _, embs in out)
+    # an empty Enc stands for "not rebuilt"
+    assert all(len(enc[0]) == 0 for _, _, enc in out)
     assert {s: g for s, (g, _) in merged.items()} == gids  # not mutated
     snap = m.metrics.snapshot("mining")
     assert snap["mining.signatures"] == 3
@@ -306,3 +313,191 @@ def test_prescreen_mines_equal_to_core(seed, rs):
     assert core.patterns == dev.patterns
     snap = m.metrics.snapshot("mining")
     assert 0 < snap["mining.canon_skipped"] < snap["mining.signatures"]
+
+
+def _rebuild_as_emb_tuples(m, pattern, enc, sig, et_rows, child_raw):
+    """The child-embedding rebuild as it was when the frontier carried
+    ``Emb`` tuples: the same numpy ops, then one tuple per deduped row.
+    The oracle for the encoded rebuild."""
+    (slot_kind, slot_idx), ptr = signature_to_extkey(sig)
+    nv = len(pattern_vertices(pattern))
+    n_pat = len(pattern)
+    vmap = canonical_map(child_raw)
+    gid_all, phi_all, psi_all = enc
+    et = np.concatenate(et_rows, axis=0)
+    e_i, t_i = et[:, 0], et[:, 1]
+    gids_r = gid_all[e_i].astype(np.int64)
+    tok = m.tdb.tokens[gids_r, t_i]
+    u1, u2, j = tok[:, 1], tok[:, 2], tok[:, 4]
+    phi_r = phi_all[e_i]
+    if slot_kind == "in":
+        new_phi = phi_r[:, :n_pat]
+    else:
+        new_phi = np.concatenate(
+            [phi_r[:, :slot_idx], j[:, None], phi_r[:, slot_idx:n_pat]],
+            axis=1)
+    psi_r = psi_all[e_i][:, :nv]
+    if ptr.is_vertex:
+        if ptr.u1 == nv:
+            psis = [np.concatenate([psi_r, u1[:, None]], axis=1)]
+        else:
+            psis = [psi_r]
+    elif ptr.u2 == nv + 1:
+        psis = [
+            np.concatenate([psi_r, u1[:, None], u2[:, None]], axis=1),
+            np.concatenate([psi_r, u2[:, None], u1[:, None]], axis=1),
+        ]
+    elif ptr.u2 == nv:
+        mapped_dv = psi_r[:, ptr.u1]
+        fresh_dv = np.where(mapped_dv == u1, u2, u1)
+        psis = [np.concatenate([psi_r, fresh_dv[:, None]], axis=1)]
+    else:
+        psis = [psi_r]
+    nv_child = psis[0].shape[1]
+    perm = np.asarray([vmap[pv] for pv in range(nv_child)])
+    n_phi = new_phi.shape[1]
+    variants = []
+    for ps in psis:
+        canon = np.empty_like(ps)
+        canon[:, perm] = ps
+        variants.append(np.concatenate(
+            [gids_r[:, None], new_phi, canon], axis=1))
+    if len(variants) == 2:
+        rows = np.stack(variants, axis=1).reshape(
+            2 * len(et), 1 + n_phi + nv_child)
+    else:
+        rows = variants[0]
+    _, first = np.unique(rows, axis=0, return_index=True)
+    rows = rows[np.sort(first)]
+    return [
+        (
+            int(r[0]),
+            tuple(int(x) for x in r[1:1 + n_phi]),
+            tuple(enumerate(int(x) for x in r[1 + n_phi:])),
+        )
+        for r in rows
+    ]
+
+
+@pytest.mark.parametrize("seed,rs", [(3, True), (9, True), (17, True),
+                                     (5, False)])
+def test_rebuild_encodes_the_tuple_rebuild(seed, rs):
+    """Every rebuild of a mine returns, bit for bit and row for row,
+    ``encode_embeddings`` of the ``Emb`` tuples the tuple rebuild
+    made from the same inputs."""
+    db = random_db(seed, n_seq=8, n_steps=5, n_v=5)
+    m = AcceleratedMiner(db)
+    calls = []
+    rebuild = m._rebuild_embeddings
+
+    def capture(*args):
+        out = rebuild(*args)
+        calls.append((args, out))
+        return out
+
+    m._rebuild_embeddings = capture
+    m._mine(2, 5, rs=rs)
+    assert len(calls) > 10
+    for args, got in calls:
+        want = encode_embeddings(
+            _rebuild_as_emb_tuples(m, *args), m.ni, m.nv)
+        for a, b in zip(got, want):
+            assert a.dtype == b.dtype and a.shape == b.shape
+            assert np.array_equal(a, b)
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_decode_inverts_encode(seed):
+    """``decode_embeddings`` is the exact inverse of
+    ``encode_embeddings``: the root's empty phi and psi, full rows and
+    every length between, as Python ints (the checkpoint writes them)."""
+    rng = np.random.default_rng(seed)
+    ni, nv = 8, 6
+    embs = [(g, (), ()) for g in range(5)]
+    for _ in range(60):
+        phi = tuple(sorted(
+            rng.choice(40, int(rng.integers(0, ni + 1)), replace=False)
+            .tolist()))
+        psi = tuple(enumerate(
+            rng.choice(30, int(rng.integers(0, nv + 1)), replace=False)
+            .tolist()))
+        embs.append((int(rng.integers(0, 1000)), phi, psi))
+    enc = encode_embeddings(embs, ni, nv)
+    back = decode_embeddings(enc)
+    assert back == embs
+    assert all(type(x) is int
+               for g, ph, ps in back for x in (g, *ph, *sum(ps, ())))
+    assert decode_embeddings(encode_embeddings([], ni, nv)) == []
+
+
+def test_root_item_is_the_encoded_root():
+    """The miner's encoded root is ``encode_embeddings`` of the root's
+    ``Emb`` list (every sequence, empty phi and psi)."""
+    db = random_db(4, n_seq=7)
+    m = AcceleratedMiner(db)
+    root, enc = m._root_item()
+    embs = [(g, (), ()) for g in range(len(db))]
+    assert root == ()
+    for a, b in zip(enc, encode_embeddings(embs, m.ni, m.nv)):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+    assert decode_embeddings(enc) == embs
+    assert (enc[1] == PAD_PHI).all() and (enc[2] == PAD_PSI).all()
+
+
+@pytest.mark.parametrize("seed", [4, 9, 13])
+def test_expand_children_batch_takes_emb_lists_or_enc(seed):
+    """A slice handed in as ``Emb`` lists expands to the same children,
+    gid sets and decoded embedding sets as the same slice as ``Enc``."""
+    db = random_db(seed, n_seq=8, n_steps=5, n_v=5)
+    m = AcceleratedMiner(db)
+    kids = m.expand_children_batch([m._root_item()], 2)[0]
+    assert kids
+    as_enc = [(c, e) for c, _, e in kids]
+    as_embs = [(c, decode_embeddings(e)) for c, _, e in kids]
+
+    def view(out):
+        return [{c: (g, set(decode_embeddings(e))) for c, g, e in ks}
+                for ks in out]
+
+    a = view(m.expand_children_batch(as_enc, 2))
+    b = view(AcceleratedMiner(db).expand_children_batch(as_embs, 2))
+    assert a == b
+    assert any(a)
+
+
+@pytest.mark.parametrize("rs", [True, False])
+def test_rebuild_rows_counts_the_kept_frontier(rs):
+    """``rebuild_rows`` is the summed length of the embeddings of every
+    child the expansions hand back."""
+    db = random_db(2, n_seq=8, n_steps=4, n_v=4)
+    m = AcceleratedMiner(db)
+    total = 0
+    batch = m.expand_children_batch
+
+    def count(*args, **kw):
+        nonlocal total
+        out = batch(*args, **kw)
+        total += sum(len(e[0]) for ks in out for _, _, e in ks)
+        return out
+
+    m.expand_children_batch = count
+    m._mine(2, 4, rs=rs)
+    snap = m.metrics.snapshot("mining")
+    assert snap["mining.rebuild_rows"] == total > 0
+
+
+@pytest.mark.parametrize("rs", [True, False])
+def test_mine_never_encodes_emb_tuples(monkeypatch, rs):
+    """From the root to the last wave the frontier stays encoded: a mine
+    without a checkpoint never calls ``encode_embeddings``, and finds
+    the host reference's patterns."""
+    from repro.mining import driver
+
+    def refuse(*args, **kw):
+        raise AssertionError("encode_embeddings called inside a mine")
+
+    monkeypatch.setattr(driver, "encode_embeddings", refuse)
+    db = random_db(6, n_seq=8, n_steps=5, n_v=5)
+    dev = AcceleratedMiner(db)._mine(2, 5, rs=rs)
+    core = (mine_gtrace_rs if rs else mine_gtrace)(db, 2, max_len=5)
+    assert dev.patterns == core.patterns
